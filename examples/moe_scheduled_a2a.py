@@ -20,7 +20,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_smoke_config
-from repro.kernels.compat import shard_map
 from repro.core.scheduler import TranslationAwareScheduler
 from repro.models.moe import moe_block_ep, init_moe
 from repro.models.base import ParamBuilder
@@ -47,24 +46,26 @@ def main():
 
     def run(x, params, use_plan):
         def inner(x, wi_g, wi_u, wo, router):
-            p = {"wi_gate": wi_g[0], "wi_up": wi_u[0], "wo": wo[0],
-                 "router": router}
-            y, aux = moe_block_ep(p, cfg, x, "model",
-                                  plan=plan if use_plan else None)
-            return y
+            p = {"wi_gate": wi_g, "wi_up": wi_u, "wo": wo, "router": router}
+            if not use_plan:
+                return moe_block_ep(p, cfg, x, "model")[0]
+            # Producing compute the warm-up chunk hides under.
+            overlap = (lambda h: jnp.tanh(h @ router), x)
+            return moe_block_ep(p, cfg, x, "model", plan=plan,
+                                overlap_compute=overlap)[0]
         espec = P("model", None, None)
-        return shard_map(
+        return jax.shard_map(
             inner, mesh=mesh,
             in_specs=(P(), espec, espec, espec, P()),
             out_specs=P(), check_vma=False,
-        )(x, params["wi_gate"][None], params["wi_up"][None],
-          params["wo"][None], params["router"])
+        )(x, params["wi_gate"], params["wi_up"], params["wo"],
+          params["router"])
 
     y0 = jax.jit(lambda x, p: run(x, p, False))(x, params)
     print("EP MoE (unscheduled) output:", np.asarray(y0).shape,
           "finite:", bool(np.isfinite(np.asarray(y0)).all()))
     # The scheduled path wires the warm-up chunk through core.overlap.
-    y1 = jax.jit(lambda x, p: run(x, p, False))(x, params)
+    y1 = jax.jit(lambda x, p: run(x, p, True))(x, params)
     np.testing.assert_allclose(np.asarray(y0), np.asarray(y1), rtol=1e-5)
     print("scheduled == unscheduled outputs: OK")
 

@@ -34,57 +34,74 @@ def _a2a(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
                           tiled=True)
 
 
+def _a2a_blocks(xb: jnp.ndarray, axis_name: str) -> jnp.ndarray:
+    """All-to-all of peer blocks ``xb`` [n, r, ...] -> [n, r, ...].
+
+    Chunks are cut along the rows *within* each peer block (axis 1): a
+    slice of the flat leading dim would re-deal rows to the wrong peers."""
+    n, r = xb.shape[:2]
+    out = _a2a(xb.reshape(n * r, *xb.shape[2:]), axis_name)
+    return out.reshape(xb.shape)
+
+
 def warmup_all_to_all(x: jnp.ndarray, axis_name: str, *,
                       warmup_rows: int,
                       compute_fn: Callable[[jnp.ndarray], jnp.ndarray],
                       compute_arg: jnp.ndarray):
     """All-to-all of ``x`` with a warm-up head chunk overlapped with compute.
 
-    ``x``: [rows, ...] with rows divisible among peers (leading dim is the
-    peer-partitioned dim).  ``compute_fn(compute_arg)`` is the producing
-    compute the transfer tail depends on; the warm-up chunk has no data
-    dependency on it, so XLA schedules the small collective concurrently
-    (hiding the fabric cold-start exactly as a fused pre-translation kernel
-    hides Link-TLB walks).
+    ``x``: [rows, ...], rows divisible among the peers (the leading dim is
+    the peer-partitioned dim, as for a tiled ``lax.all_to_all``).  The head
+    chunk takes the first rows of every peer's block.
+    ``compute_fn(compute_arg)`` is the producing compute the transfer tail
+    depends on; the warm-up chunk has no data dependency on it, so XLA
+    schedules the small collective concurrently (hiding the fabric
+    cold-start exactly as a fused pre-translation kernel hides Link-TLB
+    walks).
 
     Returns ``(a2a(x), compute_fn(compute_arg))``.
     """
     n = lax.psum(1, axis_name)
     rows = x.shape[0]
+    xb = x.reshape(n, rows // n, *x.shape[1:])
     # Round the warm-up to a whole number of rows per peer.
-    per_peer = max(1, warmup_rows // n)
-    head_rows = min(per_peer * n, rows)
-    head = _a2a(x[:head_rows], axis_name)          # no dep on compute_fn
-    y = compute_fn(compute_arg)                    # overlaps with `head`
-    tail = _a2a(x[head_rows:], axis_name) if head_rows < rows else None
-    out = head if tail is None else jnp.concatenate([head, tail], axis=0)
-    return out, y
+    head_rows = min(max(1, warmup_rows // n), rows // n)
+    head = _a2a_blocks(xb[:, :head_rows], axis_name)   # no dep on compute_fn
+    y = compute_fn(compute_arg)                        # overlaps with `head`
+    if head_rows < rows // n:
+        tail = _a2a_blocks(xb[:, head_rows:], axis_name)
+        head = jnp.concatenate([head, tail], axis=1)
+    return head.reshape(x.shape), y
 
 
 def pipelined_all_to_all(x: jnp.ndarray, axis_name: str, *, n_chunks: int,
                          per_chunk_fn: Optional[Callable] = None):
     """Chunked all-to-all software-pipelined against per-chunk compute.
 
-    Splits the leading dim into ``n_chunks`` equal chunks; chunk ``k+1``'s
-    transfer is issued while ``per_chunk_fn`` consumes chunk ``k`` (XLA
-    overlaps the independent collective with the compute inside the scan).
-    With ``per_chunk_fn=None`` this degenerates to a chunked transfer whose
-    chunks can still overlap each other's latency.
+    Splits every peer block of ``x`` into ``n_chunks`` equal chunks of
+    rows; chunk ``k+1``'s transfer is issued while ``per_chunk_fn``
+    consumes chunk ``k`` (XLA overlaps the independent collective with the
+    compute inside the scan).  ``per_chunk_fn`` maps a received chunk
+    [n, r / n_chunks, ...] to the same shape.  With ``per_chunk_fn=None``
+    this degenerates to a chunked transfer whose chunks can still overlap
+    each other's latency.
     """
-    rows = x.shape[0]
-    n_chunks = max(1, min(n_chunks, rows))
-    while rows % n_chunks:
+    n = lax.psum(1, axis_name)
+    r = x.shape[0] // n
+    n_chunks = max(1, min(n_chunks, r))
+    while r % n_chunks:
         n_chunks -= 1
-    xs = x.reshape(n_chunks, rows // n_chunks, *x.shape[1:])
+    xs = jnp.moveaxis(
+        x.reshape(n, n_chunks, r // n_chunks, *x.shape[1:]), 1, 0)
 
     def step(carry, xc):
-        yc = _a2a(xc, axis_name)
+        yc = _a2a_blocks(xc, axis_name)
         if per_chunk_fn is not None:
             yc = per_chunk_fn(yc)
         return carry, yc
 
     _, ys = lax.scan(step, 0, xs)
-    return ys.reshape(n_chunks * (rows // n_chunks), *ys.shape[2:])
+    return jnp.moveaxis(ys, 0, 1).reshape(x.shape)
 
 
 def scheduled_all_to_all(x: jnp.ndarray, axis_name: str,

@@ -1,11 +1,8 @@
-# Pallas kernel tier: the compute hot-spots of the model zoo, written
-# against the jax-version shim in `compat.py` (CompilerParams naming,
-# shard_map location, BlockSpec order) so the whole tier tracks one file
-# across jax upgrades.  `ops` holds the jit'd public wrappers (interpret
-# mode off-TPU); `ref` the pure-jnp oracles; `repro.workloads.calibrate`
-# times these kernels to produce measured compute windows for replay.
-from . import compat  # noqa: F401  (import-time version probes)
+# Pallas kernel tier: the compute hot-spots of the model zoo.  `ops` holds
+# the jit'd public wrappers (interpret mode off-TPU, compiled on a TPU);
+# `ref` the pure-jnp oracles; `compat` the one mesh constructor;
+# `repro.workloads.calibrate` times these kernels to produce measured
+# compute windows for replay.
 from .ops import flash_attention, grouped_matmul, rmsnorm, ssd_scan
 
-__all__ = ["compat", "flash_attention", "grouped_matmul", "rmsnorm",
-           "ssd_scan"]
+__all__ = ["flash_attention", "grouped_matmul", "rmsnorm", "ssd_scan"]

@@ -1,113 +1,23 @@
-"""Single jax-version shim for the kernel / sharding tier.
+"""Mesh construction pinned to the installed jax (>= 0.9).
 
-The Pallas and shard_map APIs have been renamed repeatedly across the jax
-versions this repo must run on (>= 0.4.31):
-
-* ``pltpu.TPUCompilerParams`` (<= 0.6) became ``pltpu.CompilerParams``;
-* ``shard_map`` moved from ``jax.experimental.shard_map`` to the top-level
-  ``jax.shard_map`` namespace, and its replication-check kwarg was renamed
-  ``check_rep`` -> ``check_vma``;
-* ``pl.BlockSpec`` swapped its positional argument order from
-  ``(index_map, block_shape)`` to ``(block_shape, index_map)`` around
-  0.4.31-0.4.33;
-* ``pltpu.PrefetchScalarGridSpec`` is slated to fold into ``pl.GridSpec``.
-
-Every kernel (``rmsnorm``/``flash_attention``/``grouped_matmul``/
-``ssd_scan``/``ops``), the sharding rules (``repro.parallel.sharding``) and
-the overlap-primitive call sites import the resolved names from here, so a
-jax upgrade is a one-file change.  Resolution happens once at import time;
-the probes are pure introspection (no arrays, no device access).
+``jax.make_mesh`` builds Explicit axes by default since jax 0.7, and
+``with_sharding_constraint`` (the logical-axis rules in
+``repro.models.base``) only accepts Auto axes.  Every mesh in the repo is
+built here so that choice lives in one place.
 """
 from __future__ import annotations
 
-import inspect
-from typing import Any, Callable, Optional, Sequence
+from typing import Sequence
 
 import jax
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import AxisType
 
-__all__ = ["CompilerParams", "PrefetchScalarGridSpec", "block_spec",
-           "shard_map", "make_mesh"]
+__all__ = ["make_mesh"]
 
 
-# ------------------------------------------------------------- CompilerParams
-# New spelling first: on versions that carry both, TPUCompilerParams is the
-# deprecated alias and warns.
-if hasattr(pltpu, "CompilerParams"):
-    CompilerParams = pltpu.CompilerParams
-elif hasattr(pltpu, "TPUCompilerParams"):
-    CompilerParams = pltpu.TPUCompilerParams
-else:  # pragma: no cover - jax < 0.4.31
-    raise ImportError(
-        "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-        "TPUCompilerParams; jax >= 0.4.31 is required")
-
-
-# ----------------------------------------------------- PrefetchScalarGridSpec
-if hasattr(pltpu, "PrefetchScalarGridSpec"):
-    PrefetchScalarGridSpec = pltpu.PrefetchScalarGridSpec
-else:  # pragma: no cover - future jax: folded into pl.GridSpec
-    def PrefetchScalarGridSpec(*, num_scalar_prefetch: int, grid, in_specs,
-                               out_specs, scratch_shapes=()):
-        return pl.GridSpec(grid=grid, in_specs=in_specs, out_specs=out_specs,
-                           num_scalar_prefetch=num_scalar_prefetch,
-                           scratch_shapes=scratch_shapes)
-
-
-# ------------------------------------------------------------------ BlockSpec
-def _blockspec_old_order() -> bool:  # pragma: no cover - version probe
-    try:
-        params = list(inspect.signature(pl.BlockSpec).parameters)
-    except (TypeError, ValueError):
-        return False
-    return bool(params) and params[0] == "index_map"
-
-
-_OLD_BLOCKSPEC = _blockspec_old_order()
-
-
-def block_spec(block_shape: Optional[Sequence[Optional[int]]] = None,
-               index_map: Optional[Callable[..., Any]] = None,
-               **kwargs) -> pl.BlockSpec:
-    """``pl.BlockSpec`` in the modern ``(block_shape, index_map)`` order."""
-    if _OLD_BLOCKSPEC:  # pragma: no cover - old jax only
-        return pl.BlockSpec(index_map, block_shape, **kwargs)
-    return pl.BlockSpec(block_shape, index_map, **kwargs)
-
-
-# ------------------------------------------------------------------ shard_map
-def _resolve_shard_map():
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-    params = inspect.signature(fn).parameters
-    check_kw = "check_vma" if "check_vma" in params else (
-        "check_rep" if "check_rep" in params else None)
-    return fn, check_kw
-
-
-_SHARD_MAP, _CHECK_KW = _resolve_shard_map()
-
-
-def shard_map(f: Callable, *, mesh, in_specs, out_specs,
-              check_vma: Optional[bool] = None, **kwargs) -> Callable:
-    """``jax.shard_map`` across its namespace / kwarg renames.
-
-    ``check_vma`` follows the newest spelling and is translated to
-    ``check_rep`` on older jax; ``None`` leaves the version default.
-    """
-    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-    if check_vma is not None and _CHECK_KW is not None:
-        kw[_CHECK_KW] = check_vma
-    return _SHARD_MAP(f, **kw)
-
-
-# ------------------------------------------------------------------ make_mesh
-def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """``jax.make_mesh`` (>= 0.4.35) with a mesh_utils fallback."""
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
-    from jax.experimental import mesh_utils  # pragma: no cover - old jax
-    devices = mesh_utils.create_device_mesh(tuple(axis_shapes))
-    return jax.sharding.Mesh(devices, tuple(axis_names))
+def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
+              devices=None):
+    """``jax.make_mesh`` with every axis Auto (GSPMD-propagated)."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)

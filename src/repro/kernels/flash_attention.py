@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams, block_spec
 
 NEG_INF = -1e30
 
@@ -103,18 +102,18 @@ def flash_attention_kernel(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                           block_q=block_q, block_k=block_k, nk=nk),
         grid=(B * H, nq, nk),
         in_specs=[
-            block_spec((None, block_q, Dh), lambda b, i, j: (b, i, 0)),
-            block_spec((None, block_k, Dh), kv_index),
-            block_spec((None, block_k, Dh), kv_index),
+            pl.BlockSpec((None, block_q, Dh), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((None, block_k, Dh), kv_index),
+            pl.BlockSpec((None, block_k, Dh), kv_index),
         ],
-        out_specs=block_spec((None, block_q, Dh), lambda b, i, j: (b, i, 0)),
+        out_specs=pl.BlockSpec((None, block_q, Dh), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, Dh), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, Dh), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qh, kh, vh)

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams, PrefetchScalarGridSpec, block_spec
-
 
 def _gmm_kernel(offs_ref, lhs_ref, rhs_ref, out_ref, acc_ref, *,
                 block_t: int, n_experts: int):
@@ -63,22 +61,22 @@ def grouped_matmul_kernel(lhs: jnp.ndarray, rhs: jnp.ndarray,
     block_f = min(block_f, F)
     assert T % block_t == 0 and F % block_f == 0, (T, F, block_t, block_f)
 
-    grid_spec = PrefetchScalarGridSpec(
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(T // block_t, F // block_f, E),
         in_specs=[
-            block_spec((block_t, D), lambda t, f, e, offs: (t, 0)),
-            block_spec((None, D, block_f), lambda t, f, e, offs: (e, 0, f)),
+            pl.BlockSpec((block_t, D), lambda t, f, e, offs: (t, 0)),
+            pl.BlockSpec((None, D, block_f), lambda t, f, e, offs: (e, 0, f)),
         ],
-        out_specs=block_spec((block_t, block_f),
-                             lambda t, f, e, offs: (t, f)),
+        out_specs=pl.BlockSpec((block_t, block_f),
+                               lambda t, f, e, offs: (t, f)),
         scratch_shapes=[pltpu.VMEM((block_t, block_f), jnp.float32)],
     )
     return pl.pallas_call(
         functools.partial(_gmm_kernel, block_t=block_t, n_experts=E),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, F), lhs.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(group_offsets.astype(jnp.int32), lhs, rhs)

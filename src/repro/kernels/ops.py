@@ -17,7 +17,8 @@ from .ssd_scan import ssd_chunk_kernel
 from .rmsnorm import rmsnorm_kernel
 
 
-def _on_tpu() -> bool:
+def on_tpu() -> bool:
+    """Kernels compile for the chip here; elsewhere they interpret."""
     return jax.default_backend() == "tpu"
 
 
@@ -25,20 +26,20 @@ def _on_tpu() -> bool:
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
                     block_k: int = 128):
     return flash_attention_kernel(q, k, v, causal=causal, block_q=block_q,
-                                  block_k=block_k, interpret=not _on_tpu())
+                                  block_k=block_k, interpret=not on_tpu())
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "block_f"))
 def grouped_matmul(lhs, rhs, group_offsets, *, block_t: int = 128,
                    block_f: int = 128):
     return grouped_matmul_kernel(lhs, rhs, group_offsets, block_t=block_t,
-                                 block_f=block_f, interpret=not _on_tpu())
+                                 block_f=block_f, interpret=not on_tpu())
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_rows"))
 def rmsnorm(x, w, *, eps: float = 1e-6, block_rows: int = 256):
     return rmsnorm_kernel(x, w, eps=eps, block_rows=block_rows,
-                          interpret=not _on_tpu())
+                          interpret=not on_tpu())
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
@@ -71,7 +72,7 @@ def ssd_scan(x, dt, A_log, B, C, *, chunk: int = 256):
                           (b, nc, H, Q, N)).reshape(b * nc * H, Q, N)
 
     y_diag, states = ssd_chunk_kernel(xg, dtg, ag, Bg, Cg,
-                                      interpret=not _on_tpu())
+                                      interpret=not on_tpu())
     y_diag = (y_diag.reshape(b, nc, H, Q, P).transpose(0, 1, 3, 2, 4))
     states = states.reshape(b, nc, H, P, N)
 

@@ -11,8 +11,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from .compat import CompilerParams, block_spec
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
@@ -32,12 +31,12 @@ def rmsnorm_kernel(x: jnp.ndarray, w: jnp.ndarray, *, eps: float = 1e-6,
         functools.partial(_rmsnorm_kernel, eps=eps),
         grid=(T // block_rows,),
         in_specs=[
-            block_spec((block_rows, D), lambda t: (t, 0)),
-            block_spec((D,), lambda t: (0,)),
+            pl.BlockSpec((block_rows, D), lambda t: (t, 0)),
+            pl.BlockSpec((D,), lambda t: (0,)),
         ],
-        out_specs=block_spec((block_rows, D), lambda t: (t, 0)),
+        out_specs=pl.BlockSpec((block_rows, D), lambda t: (t, 0)),
         out_shape=jax.ShapeDtypeStruct((T, D), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, w)

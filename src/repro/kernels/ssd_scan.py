@@ -19,8 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from .compat import CompilerParams, block_spec
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -28,29 +27,36 @@ NEG_INF = -1e30
 def _ssd_chunk_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, s_ref, *,
                       q_len: int):
     # Blocks: x [Q, P]; dt, a [1, Q]; b, c [Q, N]; y [Q, P]; s [P, N].
+    # Mosaic lowers neither cumsum nor a [Q] -> [Q, 1] relayout, so prefix
+    # sums are masked lane reductions and row -> column moves go through
+    # an identity mask; every value stays 2-D.
     x = x_ref[...].astype(jnp.float32)
-    dt = dt_ref[0].astype(jnp.float32)          # [Q]
-    a = a_ref[0].astype(jnp.float32)            # [Q]
-    B = b_ref[...].astype(jnp.float32)               # [Q, N]
-    C = c_ref[...].astype(jnp.float32)               # [Q, N]
+    dt = dt_ref[...].astype(jnp.float32)        # [1, Q]
+    a = a_ref[...].astype(jnp.float32)          # [1, Q]
+    B = b_ref[...].astype(jnp.float32)          # [Q, N]
+    C = c_ref[...].astype(jnp.float32)          # [Q, N]
 
-    a_cum = jnp.cumsum(a)                          # [Q]
-    # L[q, k] = exp(a_cum[q] - a_cum[k]) for k <= q else 0.
-    diff = a_cum[:, None] - a_cum[None, :]
     qi = jax.lax.broadcasted_iota(jnp.int32, (q_len, q_len), 0)
     kj = jax.lax.broadcasted_iota(jnp.int32, (q_len, q_len), 1)
-    L = jnp.exp(jnp.where(kj <= qi, diff, NEG_INF))
+    causal = kj <= qi
+    diag = kj == qi
+    a_cum_col = jnp.sum(jnp.where(causal, a, 0.0), axis=1,
+                        keepdims=True)                         # [Q, 1]
+    a_cum_row = jnp.sum(jnp.where(diag, a_cum_col, 0.0), axis=0,
+                        keepdims=True)                         # [1, Q]
+    # L[q, k] = exp(a_cum[q] - a_cum[k]) for k <= q else 0.
+    L = jnp.exp(jnp.where(causal, a_cum_col - a_cum_row, NEG_INF))
 
     CB = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # [Q, Q]
-    M = CB * L * dt[None, :]
-    xdt = x * dt[:, None]
+    M = CB * L * dt
     y_ref[...] = jax.lax.dot_general(
         M, x, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32).astype(y_ref.dtype)
 
-    decay = jnp.exp(a_cum[-1] - a_cum)             # [Q]
-    xw = x * (decay * dt)[:, None]                 # [Q, P]
+    a_total = jnp.sum(a, axis=1, keepdims=True)                # [1, 1]
+    dt_col = jnp.sum(jnp.where(diag, dt, 0.0), axis=1, keepdims=True)
+    xw = x * (jnp.exp(a_total - a_cum_col) * dt_col)           # [Q, P]
     s_ref[...] = jax.lax.dot_general(
         xw, B, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32).astype(s_ref.dtype)
@@ -70,21 +76,21 @@ def ssd_chunk_kernel(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
         functools.partial(_ssd_chunk_kernel, q_len=Q),
         grid=(G,),
         in_specs=[
-            block_spec((None, Q, P), lambda g: (g, 0, 0)),
-            block_spec((None, 1, Q), lambda g: (g, 0, 0)),
-            block_spec((None, 1, Q), lambda g: (g, 0, 0)),
-            block_spec((None, Q, N), lambda g: (g, 0, 0)),
-            block_spec((None, Q, N), lambda g: (g, 0, 0)),
+            pl.BlockSpec((None, Q, P), lambda g: (g, 0, 0)),
+            pl.BlockSpec((None, 1, Q), lambda g: (g, 0, 0)),
+            pl.BlockSpec((None, 1, Q), lambda g: (g, 0, 0)),
+            pl.BlockSpec((None, Q, N), lambda g: (g, 0, 0)),
+            pl.BlockSpec((None, Q, N), lambda g: (g, 0, 0)),
         ],
         out_specs=[
-            block_spec((None, Q, P), lambda g: (g, 0, 0)),
-            block_spec((None, P, N), lambda g: (g, 0, 0)),
+            pl.BlockSpec((None, Q, P), lambda g: (g, 0, 0)),
+            pl.BlockSpec((None, P, N), lambda g: (g, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((G, Q, P), jnp.float32),
             jax.ShapeDtypeStruct((G, P, N), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(x, dt[:, None, :], a[:, None, :], B, C)

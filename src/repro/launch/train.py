@@ -12,6 +12,7 @@ import argparse
 
 from .. import configs
 from ..runtime import Trainer, TrainerConfig
+from .compile_cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -30,6 +31,7 @@ def main(argv=None):
                     choices=["none", "bf16", "int8"])
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = (configs.get_config(args.arch) if args.full
            else configs.get_smoke_config(args.arch))
     tcfg = TrainerConfig(steps=args.steps, batch_size=args.batch,

@@ -183,10 +183,13 @@ def moe_block_ep(p, cfg: ModelConfig, x: jnp.ndarray, axis_name: str,
         jnp.where(keep, a % E_loc + 1, 0))             # 0 = empty slot
 
     # ---- dispatch all-to-all (optionally warm-up-scheduled) -------------
-    if plan is not None and overlap_compute is not None:
-        recv, _ = scheduled_all_to_all(send, axis_name, plan,
-                                       compute_fn=overlap_compute[0],
-                                       compute_arg=overlap_compute[1])
+    if plan is not None:
+        # Flat [ep*C, D] rows: the schedule chunks within each peer block.
+        compute_fn, compute_arg = overlap_compute or (None, None)
+        recv, _ = scheduled_all_to_all(send.reshape(ep * C, D), axis_name,
+                                       plan, compute_fn=compute_fn,
+                                       compute_arg=compute_arg)
+        recv = recv.reshape(ep, C, D)
     else:
         recv = lax.all_to_all(send, axis_name, split_axis=0, concat_axis=0,
                               tiled=True)

@@ -18,8 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .base import (ModelConfig, ParamBuilder, stack_layer_params,
-                   stacked_specs, with_logical)
+from .base import ModelConfig, ParamBuilder, stacked_specs, with_logical
 from . import layers as L
 from .layers import KVCache
 from .moe import init_moe, moe_gather
@@ -37,9 +36,11 @@ def _has_ffn(cfg: ModelConfig) -> bool:
 
 
 # --------------------------------------------------------------------- init
-def init_block(b: ParamBuilder, cfg: ModelConfig, block_idx: int):
+def init_block(b: ParamBuilder, cfg: ModelConfig):
+    """One block's parameters.  Every block has the same structure (the
+    stack is scanned), so a layer's MoE-ness follows its position ``pos``
+    in the pattern, as in ``_layer_forward``."""
     for pos, kind in enumerate(cfg.pattern):
-        gi = block_idx * cfg.block_size + pos
         lb = b.child(f"l{pos}")
         lb.ones("ln1", (cfg.d_model,), (None,))
         if kind == "attn":
@@ -48,7 +49,7 @@ def init_block(b: ParamBuilder, cfg: ModelConfig, block_idx: int):
             init_ssm(lb, cfg)
         if _has_ffn(cfg):
             lb.ones("ln2", (cfg.d_model,), (None,))
-            if _layer_is_moe(cfg, gi):
+            if _layer_is_moe(cfg, pos):
                 init_moe(lb, cfg)
             else:
                 L.init_mlp(lb, cfg)
@@ -61,14 +62,21 @@ def init_lm(cfg: ModelConfig, key: jax.Array):
     if cfg.n_img_tokens > 0:
         b.normal("mm_proj", (cfg.d_model, cfg.d_model), ("embed", None),
                  fan_in=cfg.d_model)
-    blocks, bspecs = [], None
-    for i in range(cfg.n_blocks):
-        bb = ParamBuilder(jax.random.fold_in(key, i + 1), cfg.param_dtype)
-        init_block(bb, cfg, i)
-        blocks.append(bb.params)
-        bspecs = bb.specs
+    bspecs = {}
+
+    def one_block(k):
+        bb = ParamBuilder(k, cfg.param_dtype)
+        init_block(bb, cfg)
+        bspecs.update(bb.specs)
+        return bb.params
+
+    # One block traced and vmapped over the per-block keys: the values equal
+    # a loop over blocks, but the program no longer grows with depth (24
+    # unrolled granite blocks took over two minutes to compile for a TPU).
+    keys = jnp.stack([jax.random.fold_in(key, i + 1)
+                      for i in range(cfg.n_blocks)])
     params, specs = b.done()
-    params["blocks"] = stack_layer_params(blocks)
+    params["blocks"] = jax.vmap(one_block)(keys)
     specs["blocks"] = stacked_specs(bspecs)
     return params, specs
 

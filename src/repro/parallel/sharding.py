@@ -28,10 +28,6 @@ from typing import Any, Dict
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# Re-exported jax-version shims: every shard_map context in the repo (the
-# overlap primitives, moe_block_ep callers, tests) resolves the function
-# through here so the namespace/kwarg renames live in exactly one file.
-from ..kernels.compat import make_mesh, shard_map  # noqa: F401
 from ..models.base import logical_to_pspec
 
 

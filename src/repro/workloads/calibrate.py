@@ -71,7 +71,8 @@ class PhaseWindow:
     phase: str                 # attn_mixer | ssm_mixer | moe_ffn | dense_ffn
     kernels: tuple             # kernel names measured for this phase
     roofline_ns: float         # derive.py's per-layer roofline window
-    measured_wall_ns: float    # interpret-mode wall time of the capped slice
+    measured_wall_ns: float    # wall time of the capped slice (interpreted
+                               # off-TPU, compiled on a TPU)
     measured_flops: float      # analytic flops of the measured slice
     calibrated_ns: float = 0.0
     layers: int = 1            # layer multiplicity (anchor weight)
@@ -314,6 +315,8 @@ def calibrate(arch, shape: str, *, pod: Optional[PodSpec] = None,
                                                  pod.tp, pod.dp):
                 return prof
 
+    from ..kernels.ops import on_tpu                  # lazy: imports jax
+
     rooflines, counts = _phase_rooflines(cfg, spec, pod)
     phases: Dict[str, PhaseWindow] = {}
     for phase, roof in rooflines.items():
@@ -336,7 +339,7 @@ def calibrate(arch, shape: str, *, pod: Optional[PodSpec] = None,
 
     prof = ComputeProfile(arch=cfg.name, shape=shape, n_gpus=pod.n_gpus,
                           ep=pod.ep, tp=pod.tp, dp=pod.dp,
-                          interpret=True, phases=phases)
+                          interpret=not on_tpu(), phases=phases)
     if cache_path is not None:
         prof.save(cache_path)
     return prof

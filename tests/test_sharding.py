@@ -6,7 +6,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models.base import logical_to_pspec
 from repro.parallel.sharding import (WorkloadKind, rules_for, fit_pspec,
-                                     cache_pspecs, batch_pspec, shard_map)
+                                     cache_pspecs, batch_pspec)
 from repro.models.layers import KVCache
 from repro.models.ssd import SSMCache
 
@@ -112,7 +112,7 @@ class TestOverlapPrimitives:
         def f(x):
             return pipelined_all_to_all(x, "model", n_chunks=4)
 
-        out = jax.jit(shard_map(
+        out = jax.jit(jax.shard_map(
             f, mesh=mesh, in_specs=jax.sharding.PartitionSpec(),
             out_specs=jax.sharding.PartitionSpec(), check_vma=False))(x)
         assert jnp.allclose(out, x)
@@ -129,7 +129,7 @@ class TestOverlapPrimitives:
                                        compute_arg=x)
             return out, y
 
-        out, y = jax.jit(shard_map(
+        out, y = jax.jit(jax.shard_map(
             f, mesh=mesh,
             in_specs=(jax.sharding.PartitionSpec(),) * 2,
             out_specs=(jax.sharding.PartitionSpec(),) * 2,
@@ -153,7 +153,7 @@ class TestOverlapPrimitives:
             y, aux = moe_block_ep(pp, cfg, x, "model")
             return y
 
-        y = jax.jit(shard_map(
+        y = jax.jit(jax.shard_map(
             f, mesh=mesh,
             in_specs=(jax.sharding.PartitionSpec(),) * 5,
             out_specs=jax.sharding.PartitionSpec(), check_vma=False))(

@@ -11,13 +11,14 @@ import pytest
 
 from repro import configs
 from repro.configs.shapes import ShapeSpec
+from repro.kernels.compat import make_mesh
 from repro.launch.steps import make_train_step, make_serve_step, make_prefill_step
 from repro.optim import adamw, with_master, cosine_with_warmup
 
 
 def local_mesh():
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return make_mesh((n, 1), ("data", "model"))
 
 
 def smoke_shape(kind, seq, batch):

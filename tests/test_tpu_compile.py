@@ -1,0 +1,140 @@
+"""Compiles for a described TPU v5e: what the chip's compiler refuses fails
+here, with no chip attached.
+
+Covers the four Pallas kernels at the shapes ``workloads/calibrate.py``
+measures (interpret mode off) and the serving steps of
+granite-moe-1b-a400m at its published widths.  Nothing runs, so nothing
+here says anything about results or times.
+
+The topology is described inside a module fixture, never at import: only
+one process may load the TPU library, and every pytest-xdist worker
+imports this file.  The persistent compile cache is off around these
+compiles (an entry compiled for a described chip cannot be read back).
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import configs
+from repro.workloads.calibrate import (_CAP_EXPERTS, _CAP_FF, _CAP_HEADS,
+                                       _CAP_SEQ, _CAP_TOKENS)
+
+V5E_HBM_BYTES = 16 * 2**30
+GRANITE = "granite-moe-1b-a400m"
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """One described v5e chip, with the persistent compile cache off."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _on(chip, tree):
+    """ShapeDtypeStructs of ``tree`` placed on the described chip."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), tree)
+
+
+def _kernel_case(name, dtype):
+    """(kernel call with interpret off, argument shapes) as calibrate.py
+    measures them: granite's attention and MoE phases, mamba2's SSD."""
+    from repro.kernels.flash_attention import flash_attention_kernel
+    from repro.kernels.grouped_matmul import grouped_matmul_kernel
+    from repro.kernels.rmsnorm import rmsnorm_kernel
+    from repro.kernels.ssd_scan import ssd_chunk_kernel
+
+    g = configs.get_config(GRANITE)
+    S = jax.ShapeDtypeStruct
+    T, D = _CAP_TOKENS, g.d_model
+    if name == "rmsnorm":
+        return (lambda x, w: rmsnorm_kernel(x, w, interpret=False),
+                (S((T, D), dtype), S((D,), dtype)))
+    if name == "flash_attention":
+        H = min(g.n_heads, _CAP_HEADS)
+        KV = max(1, min(g.n_kv_heads, H))
+        q = S((1, _CAP_SEQ, H, g.d_head), dtype)
+        kv = S((1, _CAP_SEQ, KV, g.d_head), dtype)
+        return (lambda q, k, v: flash_attention_kernel(
+            q, k, v, causal=True, interpret=False), (q, kv, kv))
+    if name == "grouped_matmul":
+        E = min(g.n_experts, _CAP_EXPERTS)
+        return (lambda l, r, o: grouped_matmul_kernel(l, r, o,
+                                                      interpret=False),
+                (S((T, D), dtype), S((E, D, _CAP_FF), dtype),
+                 S((E + 1,), jnp.int32)))
+    m = configs.get_config("mamba2-780m")        # ssd_scan: calibrate's slice
+    H = min(max(1, m.d_model * m.ssm_expand // m.ssm_head_dim), 2)
+    P, N = max(m.ssm_head_dim, 8), min(max(m.ssm_state, 16), 64)
+    Q = 64
+    G = (_CAP_SEQ // Q) * H
+    return (lambda x, dt, a, B, C: ssd_chunk_kernel(x, dt, a, B, C,
+                                                    interpret=False),
+            (S((G, Q, P), dtype), S((G, Q), dtype), S((G, Q), dtype),
+             S((G, Q, N), dtype), S((G, Q, N), dtype)))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("name", ["rmsnorm", "flash_attention",
+                                  "grouped_matmul", "ssd_chunk_kernel"])
+def test_kernel_compiles_for_v5e(chip, name, dtype):
+    fn, shapes = _kernel_case(name, jnp.dtype(dtype))
+    compiled = jax.jit(fn).lower(*_on(chip, shapes)).compile()
+    assert "tpu_custom_call" in compiled.as_text()   # Mosaic, not XLA
+
+
+def _granite_steps(chip, batch, prompt_len, s_max):
+    from repro.launch.serve import make_batch, make_steps, serving_config
+    from repro.models import api
+
+    cfg = serving_config(configs.get_config(GRANITE))
+    key = jax.random.PRNGKey(0)
+    params = _on(chip, jax.eval_shape(lambda k: api.init(cfg, k)[0], key))
+    prompts = _on(chip, jax.eval_shape(
+        lambda k: make_batch(cfg, k, batch, prompt_len), key))
+    prefill, decode = make_steps(cfg, s_max)
+    return cfg, params, prompts, prefill, decode
+
+
+def _fits_one_chip(compiled):
+    ma = compiled.memory_analysis()
+    used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert used < V5E_HBM_BYTES, used
+    return ma
+
+
+def test_granite_full_width_prefill_compiles_for_v5e(chip):
+    cfg, params, prompts, prefill, _ = _granite_steps(chip, 1, 128, 160)
+    assert (cfg.n_layers, cfg.d_model, cfg.n_experts) == (24, 1024, 32)
+    ma = _fits_one_chip(prefill.lower(params, prompts).compile())
+    # bf16 weights: ~1.4 B parameters at two bytes each.
+    assert 2.5e9 < ma.argument_size_in_bytes < 3.0e9
+
+
+def test_granite_full_width_decode_compiles_for_v5e(chip):
+    _, params, prompts, prefill, decode = _granite_steps(chip, 8, 512, 544)
+    _, caches = jax.eval_shape(prefill, params, prompts)
+    tok = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=chip)
+    ma = _fits_one_chip(decode.lower(params, tok, _on(chip, caches))
+                        .compile())
+    assert ma.alias_size_in_bytes > 0                # the cache is donated
