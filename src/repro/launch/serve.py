@@ -24,7 +24,8 @@ import numpy as np
 from .. import configs
 from ..models import api
 from ..models.base import ModelConfig
-from .compile_cache import enable_compile_cache
+from ..models.scopes import UNEMBED, scope
+from .compile_cache import CompileCounter, enable_compile_cache
 
 
 def serving_config(cfg: ModelConfig) -> ModelConfig:
@@ -56,12 +57,16 @@ def make_steps(cfg: ModelConfig, s_max: int):
     """Jitted ``prefill(params, batch) -> (logits, caches)`` and
     ``decode(params, token, caches) -> (next_token, logits, caches)``;
     decode donates ``caches`` and picks the next token greedily."""
+    def prefill(params, batch):
+        return api.prefill(cfg, params, batch, s_max)
+
     def decode(params, token, caches):
         logits, caches = api.decode_step(cfg, params, token, caches)
-        return jnp.argmax(logits, axis=-1).astype(token.dtype), logits, caches
+        with scope(UNEMBED):
+            nxt = jnp.argmax(logits, axis=-1).astype(token.dtype)
+        return nxt, logits, caches
 
-    prefill = jax.jit(lambda p, b: api.prefill(cfg, p, b, s_max))
-    return prefill, jax.jit(decode, donate_argnums=(2,))
+    return jax.jit(prefill), jax.jit(decode, donate_argnums=(2,))
 
 
 @dataclass
@@ -87,41 +92,48 @@ def serve(cfg: ModelConfig, *, batch: int, prompt_len: int,
         raise ValueError(f"new_tokens must be >= 2, got {new_tokens}")
     cfg = serving_config(cfg)
     key = jax.random.PRNGKey(seed)
-    t0 = time.perf_counter()
-    params = jax.block_until_ready(init_params(cfg, key))
-    log(f"init_s {time.perf_counter() - t0:.3f}")
-    prompts = make_batch(cfg, jax.random.fold_in(key, 1), batch, prompt_len)
-    prefill, decode = make_steps(cfg, prompt_len + new_tokens)
+    with CompileCounter() as setup:
+        t0 = time.perf_counter()
+        params = jax.block_until_ready(init_params(cfg, key))
+        log(f"init_s {time.perf_counter() - t0:.3f}")
+        prompts = make_batch(cfg, jax.random.fold_in(key, 1), batch,
+                             prompt_len)
+        prefill, decode = make_steps(cfg, prompt_len + new_tokens)
 
-    t0 = time.perf_counter()
-    _, caches_s = jax.eval_shape(prefill, params, prompts)
-    prefill = prefill.lower(params, prompts).compile()
-    tok_s = jax.ShapeDtypeStruct((batch,), jnp.int32)
-    decode = decode.lower(params, tok_s, caches_s).compile()
-    compile_s = time.perf_counter() - t0
-    log(f"compile_s {compile_s:.3f}")
+        t0 = time.perf_counter()
+        _, caches_s = jax.eval_shape(prefill, params, prompts)
+        prefill = prefill.lower(params, prompts).compile()
+        tok_s = jax.ShapeDtypeStruct((batch,), jnp.int32)
+        decode = decode.lower(params, tok_s, caches_s).compile()
+        compile_s = time.perf_counter() - t0
+        log(f"compile_s {compile_s:.3f}")
 
-    # Warm-up: one call of each compiled step.
-    _, caches = prefill(params, prompts)
-    jax.block_until_ready(decode(params, jnp.zeros((batch,), jnp.int32),
-                                 caches))
+        # Warm-up: one call of each compiled step, and the first token's
+        # argmax, which would otherwise compile inside the timed prefill.
+        first_logits, caches = prefill(params, prompts)
+        tok = jnp.argmax(first_logits, axis=-1).astype(jnp.int32)
+        jax.block_until_ready(decode(params, tok, caches))
+    log(f"setup_compiles {setup}")
 
-    t0 = time.perf_counter()
-    first_logits, caches = prefill(params, prompts)
-    tok = jnp.argmax(first_logits, axis=-1).astype(jnp.int32)
-    jax.block_until_ready((tok, caches))
-    prefill_s = time.perf_counter() - t0
+    # A compile or trace counted here is a retrace inside the timed steps.
+    with CompileCounter() as timed:
+        t0 = time.perf_counter()
+        first_logits, caches = prefill(params, prompts)
+        tok = jnp.argmax(first_logits, axis=-1).astype(jnp.int32)
+        jax.block_until_ready((tok, caches))
+        prefill_s = time.perf_counter() - t0
 
-    toks = [tok]
-    logits = first_logits
-    t0 = time.perf_counter()
-    for _ in range(new_tokens - 1):
-        tok, logits, caches = decode(params, tok, caches)
-        toks.append(tok)
-    jax.block_until_ready((toks, logits))
-    decode_step_s = (time.perf_counter() - t0) / (new_tokens - 1)
+        toks = [tok]
+        logits = first_logits
+        t0 = time.perf_counter()
+        for _ in range(new_tokens - 1):
+            tok, logits, caches = decode(params, tok, caches)
+            toks.append(tok)
+        jax.block_until_ready((toks, logits))
+        decode_step_s = (time.perf_counter() - t0) / (new_tokens - 1)
     log(f"prefill_s {prefill_s:.6f}")
     log(f"decode_step_s {decode_step_s:.6f}")
+    log(f"timed_compiles {timed}")
 
     return ServeResult(
         tokens=np.stack([np.asarray(t) for t in toks], axis=1),

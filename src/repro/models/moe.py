@@ -23,6 +23,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from .base import ModelConfig, ParamBuilder, with_logical
+from .scopes import (EP_COMBINE, EP_DISPATCH, EP_EXPERTS, EP_META, EP_ROUTE,
+                     MOE_COMBINE, MOE_DISPATCH, MOE_EXPERTS, MOE_ROUTER,
+                     scope)
 
 
 def init_moe(b: ParamBuilder, cfg: ModelConfig, name: str = "moe"):
@@ -67,6 +70,38 @@ def _expert_ffn(p, x: jnp.ndarray) -> jnp.ndarray:
     return jnp.einsum("ecf,efd->ecd", h, p["wo"].astype(x.dtype))
 
 
+def _gather_experts(p, cfg: ModelConfig, buf: jnp.ndarray, dtype):
+    """buf: [B, E, C, D] -> [B, E, C, D], ``moe_gather``'s expert SwiGLU."""
+    E, D = cfg.n_experts, buf.shape[-1]
+    F = p["wi_gate"].shape[-1]
+    nch = cfg.ffn_chunks if (cfg.ffn_chunks > 1 and F % cfg.ffn_chunks == 0) else 1
+    if nch == 1:
+        g = jnp.einsum("becd,edf->becf", buf, p["wi_gate"].astype(dtype))
+        u = jnp.einsum("becd,edf->becf", buf, p["wi_up"].astype(dtype))
+        h = jax.nn.silu(g) * u
+        h = with_logical(h, ("batch", "experts", None, "expert_mlp"))
+        out_e = jnp.einsum("becf,efd->becd", h, p["wo"].astype(dtype))
+    else:
+        # F-chunked expert FFN (scan): bounds simultaneously-gathered
+        # expert-weight shards (all-gathers cannot be hoisted out of loops).
+        fc = F // nch
+        wg = p["wi_gate"].reshape(E, D, nch, fc).transpose(2, 0, 1, 3)
+        wu = p["wi_up"].reshape(E, D, nch, fc).transpose(2, 0, 1, 3)
+        wo = p["wo"].reshape(E, nch, fc, D).transpose(1, 0, 2, 3)
+
+        def step(acc, ws):
+            g_, u_, o_ = ws
+            h = jax.nn.silu(jnp.einsum("becd,edf->becf", buf,
+                                       g_.astype(dtype))) \
+                * jnp.einsum("becd,edf->becf", buf, u_.astype(dtype))
+            h = with_logical(h, ("batch", "experts", None, "expert_mlp"))
+            return acc + jnp.einsum("becf,efd->becd", h,
+                                    o_.astype(dtype)), None
+
+        out_e, _ = lax.scan(step, jnp.zeros_like(buf), (wg, wu, wo))
+    return with_logical(out_e, ("batch", "experts", None, None))
+
+
 def moe_gather(p, cfg: ModelConfig, x: jnp.ndarray):
     """MoE FFN for [B,S,D] input under pjit auto-sharding.
 
@@ -81,68 +116,48 @@ def moe_gather(p, cfg: ModelConfig, x: jnp.ndarray):
     C = _capacity(cfg, S)
 
     # routing (fp32) on [B,S,E]
-    logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32),
-                        p["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)
-    w, idx = lax.top_k(probs, k)                       # [B,S,k]
-    w = (w / jnp.sum(w, axis=-1, keepdims=True)).astype(x.dtype)
-    me = jnp.mean(probs, axis=(0, 1))
-    ce = jnp.mean(jnp.sum(jax.nn.one_hot(idx, E, dtype=jnp.float32), axis=2),
-                  axis=(0, 1))
-    aux = E * jnp.sum(me * ce) / k
+    with scope(MOE_ROUTER):
+        logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32),
+                            p["router"].astype(jnp.float32))
+        probs = jax.nn.softmax(logits, axis=-1)
+        w, idx = lax.top_k(probs, k)                   # [B,S,k]
+        w = (w / jnp.sum(w, axis=-1, keepdims=True)).astype(x.dtype)
+        me = jnp.mean(probs, axis=(0, 1))
+        ce = jnp.mean(jnp.sum(jax.nn.one_hot(idx, E, dtype=jnp.float32),
+                              axis=2), axis=(0, 1))
+        aux = E * jnp.sum(me * ce) / k
 
-    a = idx.reshape(B, S * k)                          # [B, S*k] expert ids
-    onehot = jax.nn.one_hot(a, E, dtype=jnp.int32)     # [B, S*k, E]
-    pos = jnp.cumsum(onehot, axis=1) - onehot
-    pos = jnp.take_along_axis(pos, a[..., None], axis=2)[..., 0]
-    keep = pos < C
-    safe_a = jnp.where(keep, a, 0)
-    safe_pos = jnp.where(keep, pos, C - 1)
-    xr = jnp.broadcast_to(x[:, :, None, :], (B, S, k, D)).reshape(B, S * k, D)
-    xr = jnp.where(keep[..., None], xr, 0).astype(x.dtype)
+    with scope(MOE_DISPATCH):
+        a = idx.reshape(B, S * k)                      # [B, S*k] expert ids
+        onehot = jax.nn.one_hot(a, E, dtype=jnp.int32)  # [B, S*k, E]
+        pos = jnp.cumsum(onehot, axis=1) - onehot
+        pos = jnp.take_along_axis(pos, a[..., None], axis=2)[..., 0]
+        keep = pos < C
+        safe_a = jnp.where(keep, a, 0)
+        safe_pos = jnp.where(keep, pos, C - 1)
+        xr = jnp.broadcast_to(x[:, :, None, :],
+                              (B, S, k, D)).reshape(B, S * k, D)
+        xr = jnp.where(keep[..., None], xr, 0).astype(x.dtype)
 
-    def disp(xr_row, a_row, pos_row):
-        return jnp.zeros((E, C, D), x.dtype).at[a_row, pos_row].add(xr_row)
+        def disp(xr_row, a_row, pos_row):
+            return jnp.zeros((E, C, D), x.dtype).at[a_row, pos_row].add(xr_row)
 
-    buf = jax.vmap(disp)(xr, safe_a, safe_pos)         # [B, E, C, D]
-    buf = with_logical(buf, ("batch", "experts", None, None))
+        buf = jax.vmap(disp)(xr, safe_a, safe_pos)     # [B, E, C, D]
+        buf = with_logical(buf, ("batch", "experts", None, None))
 
-    F = p["wi_gate"].shape[-1]
-    nch = cfg.ffn_chunks if (cfg.ffn_chunks > 1 and F % cfg.ffn_chunks == 0) else 1
-    if nch == 1:
-        g = jnp.einsum("becd,edf->becf", buf, p["wi_gate"].astype(x.dtype))
-        u = jnp.einsum("becd,edf->becf", buf, p["wi_up"].astype(x.dtype))
-        h = jax.nn.silu(g) * u
-        h = with_logical(h, ("batch", "experts", None, "expert_mlp"))
-        out_e = jnp.einsum("becf,efd->becd", h, p["wo"].astype(x.dtype))
-    else:
-        # F-chunked expert FFN (scan): bounds simultaneously-gathered
-        # expert-weight shards (all-gathers cannot be hoisted out of loops).
-        fc = F // nch
-        wg = p["wi_gate"].reshape(E, D, nch, fc).transpose(2, 0, 1, 3)
-        wu = p["wi_up"].reshape(E, D, nch, fc).transpose(2, 0, 1, 3)
-        wo = p["wo"].reshape(E, nch, fc, D).transpose(1, 0, 2, 3)
+    with scope(MOE_EXPERTS):
+        out_e = _gather_experts(p, cfg, buf, x.dtype)
 
-        def step(acc, ws):
-            g_, u_, o_ = ws
-            h = jax.nn.silu(jnp.einsum("becd,edf->becf", buf,
-                                       g_.astype(x.dtype))) \
-                * jnp.einsum("becd,edf->becf", buf, u_.astype(x.dtype))
-            h = with_logical(h, ("batch", "experts", None, "expert_mlp"))
-            return acc + jnp.einsum("becf,efd->becd", h,
-                                    o_.astype(x.dtype)), None
-
-        out_e, _ = lax.scan(step, jnp.zeros_like(buf), (wg, wu, wo))
-    out_e = with_logical(out_e, ("batch", "experts", None, None))
-
-    gathered = jax.vmap(lambda o, a_r, p_r: o[a_r, p_r])(
-        out_e, safe_a, safe_pos)                       # [B, S*k, D]
-    # Combine lands in the sequence-parallel layout: the cross-expert-shard
-    # reduction becomes a reduce-scatter into [B, S*k/TP, D] instead of a
-    # full all-reduce of [B, S*k, D] (granite train: -31% collective bytes).
-    gathered = with_logical(gathered, ("batch", "seq", None))
-    gathered = jnp.where(keep[..., None], gathered, 0)
-    y = (gathered.reshape(B, S, k, D) * w[..., None]).sum(axis=2)
+    with scope(MOE_COMBINE):
+        gathered = jax.vmap(lambda o, a_r, p_r: o[a_r, p_r])(
+            out_e, safe_a, safe_pos)                   # [B, S*k, D]
+        # Combine lands in the sequence-parallel layout: the
+        # cross-expert-shard reduction becomes a reduce-scatter into
+        # [B, S*k/TP, D] instead of a full all-reduce of [B, S*k, D]
+        # (granite train: -31% collective bytes).
+        gathered = with_logical(gathered, ("batch", "seq", None))
+        gathered = jnp.where(keep[..., None], gathered, 0)
+        y = (gathered.reshape(B, S, k, D) * w[..., None]).sum(axis=2)
     return y, aux
 
 
@@ -159,59 +174,66 @@ def moe_block_ep(p, cfg: ModelConfig, x: jnp.ndarray, axis_name: str,
 
     ep = lax.psum(1, axis_name)
     T, D = x.shape
-    idx, w, aux = route(p, cfg, x)                     # router is replicated
     E, k = cfg.n_experts, cfg.top_k
     E_loc = E // ep
     C = _capacity(cfg, T) * E_loc                      # capacity per shard
+    with scope(EP_ROUTE):
+        idx, w, aux = route(p, cfg, x)                 # router is replicated
+        a = idx.reshape(-1)                            # [T*k] global expert id
+        shard = a // E_loc                             # destination shard
+        # position within destination shard's receive slot for this source
+        onehot = jax.nn.one_hot(shard, ep, dtype=jnp.int32)
+        pos = jnp.cumsum(onehot, axis=0) - onehot
+        pos = jnp.take_along_axis(pos, shard[:, None], axis=1)[:, 0]
+        keep = pos < C
+        safe_shard = jnp.where(keep, shard, 0)
+        safe_pos = jnp.where(keep, pos, C - 1)
 
-    a = idx.reshape(-1)                                # [T*k] global expert id
-    shard = a // E_loc                                 # destination shard
-    # position within destination shard's receive slot for this source
-    onehot = jax.nn.one_hot(shard, ep, dtype=jnp.int32)
-    pos = jnp.cumsum(onehot, axis=0) - onehot
-    pos = jnp.take_along_axis(pos, shard[:, None], axis=1)[:, 0]
-    keep = pos < C
-    safe_shard = jnp.where(keep, shard, 0)
-    safe_pos = jnp.where(keep, pos, C - 1)
-
-    xr = jnp.repeat(x, k, axis=0)
-    send = jnp.zeros((ep, C, D), x.dtype)
-    send = send.at[safe_shard, safe_pos].add(
-        jnp.where(keep[:, None], xr, 0).astype(x.dtype))
-    send_meta = jnp.zeros((ep, C), jnp.int32)
-    send_meta = send_meta.at[safe_shard, safe_pos].add(
-        jnp.where(keep, a % E_loc + 1, 0))             # 0 = empty slot
+    with scope(EP_DISPATCH):
+        xr = jnp.repeat(x, k, axis=0)
+        send = jnp.zeros((ep, C, D), x.dtype)
+        send = send.at[safe_shard, safe_pos].add(
+            jnp.where(keep[:, None], xr, 0).astype(x.dtype))
+    with scope(EP_META):
+        send_meta = jnp.zeros((ep, C), jnp.int32)
+        send_meta = send_meta.at[safe_shard, safe_pos].add(
+            jnp.where(keep, a % E_loc + 1, 0))         # 0 = empty slot
 
     # ---- dispatch all-to-all (optionally warm-up-scheduled) -------------
-    if plan is not None:
-        # Flat [ep*C, D] rows: the schedule chunks within each peer block.
-        compute_fn, compute_arg = overlap_compute or (None, None)
-        recv, _ = scheduled_all_to_all(send.reshape(ep * C, D), axis_name,
-                                       plan, compute_fn=compute_fn,
-                                       compute_arg=compute_arg)
-        recv = recv.reshape(ep, C, D)
-    else:
-        recv = lax.all_to_all(send, axis_name, split_axis=0, concat_axis=0,
-                              tiled=True)
-    recv_meta = lax.all_to_all(send_meta, axis_name, split_axis=0,
-                               concat_axis=0, tiled=True)
+    with scope(EP_DISPATCH):
+        if plan is not None:
+            # Flat [ep*C, D] rows: the schedule chunks within each peer block.
+            compute_fn, compute_arg = overlap_compute or (None, None)
+            recv, _ = scheduled_all_to_all(send.reshape(ep * C, D), axis_name,
+                                           plan, compute_fn=compute_fn,
+                                           compute_arg=compute_arg)
+            recv = recv.reshape(ep, C, D)
+        else:
+            recv = lax.all_to_all(send, axis_name, split_axis=0,
+                                  concat_axis=0, tiled=True)
+    with scope(EP_META):
+        recv_meta = lax.all_to_all(send_meta, axis_name, split_axis=0,
+                                   concat_axis=0, tiled=True)
 
     # ---- local expert compute (masked batched FFN over local experts) ---
-    recv_flat = recv.reshape(ep * C, D)
-    eid = (recv_meta.reshape(-1) - 1)                  # -1 = empty
-    buf = jnp.zeros((E_loc, ep * C, D), x.dtype)
-    sel = jax.nn.one_hot(eid, E_loc, dtype=x.dtype)    # [ep*C, E_loc]
-    buf = jnp.einsum("te,td->etd", sel, recv_flat)
-    g = jnp.einsum("etd,edf->etf", buf, p["wi_gate"].astype(x.dtype))
-    u = jnp.einsum("etd,edf->etf", buf, p["wi_up"].astype(x.dtype))
-    h = jax.nn.silu(g) * u
-    out_local = jnp.einsum("etf,efd->etd", h, p["wo"].astype(x.dtype))
-    out_flat = jnp.einsum("etd,te->td", out_local, sel)
+    with scope(EP_EXPERTS):
+        recv_flat = recv.reshape(ep * C, D)
+        eid = (recv_meta.reshape(-1) - 1)              # -1 = empty
+        buf = jnp.zeros((E_loc, ep * C, D), x.dtype)
+        sel = jax.nn.one_hot(eid, E_loc, dtype=x.dtype)  # [ep*C, E_loc]
+        buf = jnp.einsum("te,td->etd", sel, recv_flat)
+        g = jnp.einsum("etd,edf->etf", buf, p["wi_gate"].astype(x.dtype))
+        u = jnp.einsum("etd,edf->etf", buf, p["wi_up"].astype(x.dtype))
+        h = jax.nn.silu(g) * u
+        out_local = jnp.einsum("etf,efd->etd", h, p["wo"].astype(x.dtype))
+        out_flat = jnp.einsum("etd,te->td", out_local, sel)
 
     # ---- combine all-to-all back ----------------------------------------
-    back = lax.all_to_all(out_flat.reshape(ep, C, D), axis_name,
-                          split_axis=0, concat_axis=0, tiled=True)
-    gathered = back[safe_shard, safe_pos]
-    gathered = jnp.where(keep[:, None], gathered, 0)
-    y = (gathered.reshape(T, k, D) * w[..., None].astype(x.dtype)).sum(axis=1)
+    with scope(EP_COMBINE):
+        back = lax.all_to_all(out_flat.reshape(ep, C, D), axis_name,
+                              split_axis=0, concat_axis=0, tiled=True)
+        gathered = back[safe_shard, safe_pos]
+        gathered = jnp.where(keep[:, None], gathered, 0)
+        y = (gathered.reshape(T, k, D)
+             * w[..., None].astype(x.dtype)).sum(axis=1)
     return y, aux

@@ -22,6 +22,7 @@ from .base import ModelConfig, ParamBuilder, stacked_specs, with_logical
 from . import layers as L
 from .layers import KVCache
 from .moe import init_moe, moe_gather
+from .scopes import ATTN, EMBED, FFN, LAYER_SCAN, SSM, UNEMBED, scope
 from .ssd import SSMCache, init_ssm, ssm_layer, ssm_prefill, ssm_decode, ssm_dims
 
 
@@ -208,21 +209,23 @@ def _block_prefill(cfg: ModelConfig, bp, x, s_max: int):
     caches = {}
     for pos, kind in enumerate(cfg.pattern):
         p = bp[f"l{pos}"]
-        h = L.rmsnorm(x, p["ln1"], cfg.norm_eps)
-        if kind == "attn":
-            h, c = L.attention_prefill(p["attn"], cfg, h, s_max,
-                                       window=cfg.sliding_window)
-        else:
-            h, c = ssm_prefill(p["ssm"], cfg, h)
-        caches[f"l{pos}"] = c
-        x = x + h
-        if _has_ffn(cfg):
-            h = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
-            if _layer_is_moe(cfg, pos):
-                h, _ = moe_gather(p["moe"], cfg, h)
+        with scope(ATTN if kind == "attn" else SSM):
+            h = L.rmsnorm(x, p["ln1"], cfg.norm_eps)
+            if kind == "attn":
+                h, c = L.attention_prefill(p["attn"], cfg, h, s_max,
+                                           window=cfg.sliding_window)
             else:
-                h = L.mlp(p["mlp"], h, n_chunks=cfg.ffn_chunks)
+                h, c = ssm_prefill(p["ssm"], cfg, h)
+            caches[f"l{pos}"] = c
             x = x + h
+        if _has_ffn(cfg):
+            with scope(FFN):
+                h = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
+                if _layer_is_moe(cfg, pos):
+                    h, _ = moe_gather(p["moe"], cfg, h)
+                else:
+                    h = L.mlp(p["mlp"], h, n_chunks=cfg.ffn_chunks)
+                x = x + h
     return x, caches
 
 
@@ -230,68 +233,77 @@ def _block_decode(cfg: ModelConfig, bp, x, caches):
     new = {}
     for pos, kind in enumerate(cfg.pattern):
         p = bp[f"l{pos}"]
-        h = L.rmsnorm(x, p["ln1"], cfg.norm_eps)
-        if kind == "attn":
-            h, c = L.attention_decode(p["attn"], cfg, h, caches[f"l{pos}"],
-                                      window=cfg.sliding_window)
-        else:
-            h, c = ssm_decode(p["ssm"], cfg, h, caches[f"l{pos}"])
-        new[f"l{pos}"] = c
-        x = x + h
-        if _has_ffn(cfg):
-            h = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
-            if _layer_is_moe(cfg, pos):
-                h, _ = moe_gather(p["moe"], cfg, h)
+        with scope(ATTN if kind == "attn" else SSM):
+            h = L.rmsnorm(x, p["ln1"], cfg.norm_eps)
+            if kind == "attn":
+                h, c = L.attention_decode(p["attn"], cfg, h,
+                                          caches[f"l{pos}"],
+                                          window=cfg.sliding_window)
             else:
-                h = L.mlp(p["mlp"], h, n_chunks=cfg.ffn_chunks)
+                h, c = ssm_decode(p["ssm"], cfg, h, caches[f"l{pos}"])
+            new[f"l{pos}"] = c
             x = x + h
+        if _has_ffn(cfg):
+            with scope(FFN):
+                h = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
+                if _layer_is_moe(cfg, pos):
+                    h, _ = moe_gather(p["moe"], cfg, h)
+                else:
+                    h = L.mlp(p["mlp"], h, n_chunks=cfg.ffn_chunks)
+                x = x + h
     return x, new
 
 
 def prefill(cfg: ModelConfig, params, tokens: jnp.ndarray, s_max: int,
             img_embeds: Optional[jnp.ndarray] = None):
     """Returns (last-token logits [B,V], stacked caches)."""
-    x = L.embed(params, cfg, tokens)
-    if cfg.n_img_tokens > 0:
-        img = jnp.einsum("bnd,de->bne", img_embeds.astype(cfg.dtype),
-                         params["mm_proj"].astype(cfg.dtype))
-        x = jnp.concatenate([img, x], axis=1)
+    with scope(EMBED):
+        x = L.embed(params, cfg, tokens)
+        if cfg.n_img_tokens > 0:
+            img = jnp.einsum("bnd,de->bne", img_embeds.astype(cfg.dtype),
+                             params["mm_proj"].astype(cfg.dtype))
+            x = jnp.concatenate([img, x], axis=1)
 
     def step(x, bp):
         x, caches = _block_prefill(cfg, bp, x, s_max)
         return x, caches
 
-    if cfg.scan_layers and cfg.n_blocks > 1:
-        x, caches = lax.scan(step, x, params["blocks"])
-    else:
-        cl = []
-        for i in range(cfg.n_blocks):
-            bp = jax.tree.map(lambda v: v[i], params["blocks"])
-            x, c = step(x, bp)
-            cl.append(c)
-        caches = jax.tree.map(lambda *xs: jnp.stack(xs), *cl)
-    logits = L.unembed(params, cfg, x[:, -1:])
-    return logits[:, 0], caches
+    with scope(LAYER_SCAN):
+        if cfg.scan_layers and cfg.n_blocks > 1:
+            x, caches = lax.scan(step, x, params["blocks"])
+        else:
+            cl = []
+            for i in range(cfg.n_blocks):
+                bp = jax.tree.map(lambda v: v[i], params["blocks"])
+                x, c = step(x, bp)
+                cl.append(c)
+            caches = jax.tree.map(lambda *xs: jnp.stack(xs), *cl)
+    with scope(UNEMBED):
+        logits = L.unembed(params, cfg, x[:, -1:])[:, 0]
+    return logits, caches
 
 
 def decode_step(cfg: ModelConfig, params, token: jnp.ndarray, caches):
     """token: [B] -> (logits [B,V], new caches).  Caches stacked over blocks."""
-    x = L.embed(params, cfg, token[:, None])
+    with scope(EMBED):
+        x = L.embed(params, cfg, token[:, None])
 
     def step(x, bc):
         bp, cache = bc
         x, new = _block_decode(cfg, bp, x, cache)
         return x, new
 
-    if cfg.scan_layers and cfg.n_blocks > 1:
-        x, new_caches = lax.scan(step, x, (params["blocks"], caches))
-    else:
-        nl = []
-        for i in range(cfg.n_blocks):
-            bp = jax.tree.map(lambda v: v[i], params["blocks"])
-            cache = jax.tree.map(lambda v: v[i], caches)
-            x, c = step(x, (bp, cache))
-            nl.append(c)
-        new_caches = jax.tree.map(lambda *xs: jnp.stack(xs), *nl)
-    logits = L.unembed(params, cfg, x)
-    return logits[:, 0], new_caches
+    with scope(LAYER_SCAN):
+        if cfg.scan_layers and cfg.n_blocks > 1:
+            x, new_caches = lax.scan(step, x, (params["blocks"], caches))
+        else:
+            nl = []
+            for i in range(cfg.n_blocks):
+                bp = jax.tree.map(lambda v: v[i], params["blocks"])
+                cache = jax.tree.map(lambda v: v[i], caches)
+                x, c = step(x, (bp, cache))
+                nl.append(c)
+            new_caches = jax.tree.map(lambda *xs: jnp.stack(xs), *nl)
+    with scope(UNEMBED):
+        logits = L.unembed(params, cfg, x)[:, 0]
+    return logits, new_caches
