@@ -10,6 +10,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox import gmm as megablox_gmm
 
 from .flash_attention import flash_attention_kernel
 from .grouped_matmul import grouped_matmul_kernel
@@ -34,6 +35,45 @@ def grouped_matmul(lhs, rhs, group_offsets, *, block_t: int = 128,
                    block_f: int = 128):
     return grouped_matmul_kernel(lhs, rhs, group_offsets, block_t=block_t,
                                  block_f=block_f, interpret=not on_tpu())
+
+
+GMM_ROWS = 128              # rows per tile: beat 256 and 512 on a v5e
+GMM_VMEM_BYTES = 15 << 20   # a kernel may hold 16 MiB of VMEM on a v5e
+
+
+def gmm_tiling(m: int, k: int, n: int, itemsize: int):
+    """``gmm``'s (rows, depth, columns) tile for [m, k] x [G, k, n].
+
+    The depth is all of ``k``, so a weight block's index changes only with
+    its group or column tile, and each group's weights stream in once per
+    column tile.  The columns are the widest 128-multiple dividing ``n``
+    whose double-buffered blocks and float32 accumulator fit
+    ``GMM_VMEM_BYTES`` (all of ``n`` when it is no 128-multiple)."""
+    tm = min(GMM_ROWS, -(-m // 16) * 16)
+
+    def vmem(tn):
+        return 2 * itemsize * (tm * k + k * tn + tm * tn) + 4 * tm * tn
+
+    if n % 128:
+        return tm, k, n
+    fits = [c for c in range(128, n + 1, 128)
+            if n % c == 0 and vmem(c) <= GMM_VMEM_BYTES]
+    return tm, k, max(fits, default=128)
+
+
+@jax.jit
+def gmm(lhs, rhs, group_sizes):
+    """Grouped matmul: ``lhs`` rows in consecutive groups, group ``g``
+    (``group_sizes[g]`` rows) times ``rhs[g]``; [m, k] x [G, k, n] ->
+    [m, n] in ``lhs``'s dtype, accumulated in float32.  Rows past
+    ``sum(group_sizes)`` are left undefined."""
+    m, k = lhs.shape
+    tiling = gmm_tiling(m, k, rhs.shape[2], lhs.dtype.itemsize)
+    pad = -m % tiling[0]
+    out = megablox_gmm(jnp.pad(lhs, ((0, pad), (0, 0))), rhs, group_sizes,
+                       preferred_element_type=lhs.dtype, tiling=tiling,
+                       interpret=not on_tpu())
+    return out[:m]
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_rows"))

@@ -11,6 +11,9 @@
                   axis — the *exact* collective the paper studies — and the
                   dispatch collective can be scheduled with the
                   translation-aware warm-up plan (repro.core.overlap).
+                  The receiving shard sorts its rows by local expert and
+                  runs each row through its own expert only, with a
+                  grouped matmul (``kernels.ops.gmm``).
 
 Both paths share routing; both drop tokens beyond capacity (GShard-style)
 with residual passthrough.
@@ -22,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..kernels import ops
 from .base import ModelConfig, ParamBuilder, with_logical
 from .scopes import (EP_COMBINE, EP_DISPATCH, EP_EXPERTS, EP_META, EP_ROUTE,
                      MOE_COMBINE, MOE_DISPATCH, MOE_EXPERTS, MOE_ROUTER,
@@ -161,6 +165,36 @@ def moe_gather(p, cfg: ModelConfig, x: jnp.ndarray):
     return y, aux
 
 
+def _local_groups(recv_meta: jnp.ndarray, n_local: int):
+    """Received slots grouped by local expert.
+
+    ``recv_meta`` holds each slot's local expert id + 1 (0 = empty).
+    Returns ``order``, the slots sorted by expert with the empty ones last
+    (stable, so slot order holds within an expert), and ``sizes``
+    [n_local] int32, each expert's row count."""
+    meta = recv_meta.reshape(-1)
+    key = jnp.where(meta == 0, n_local, meta - 1)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.bincount(key, length=n_local + 1)[:n_local]
+    return order, sizes.astype(jnp.int32)
+
+
+def _local_experts(p, rows: jnp.ndarray, recv_meta: jnp.ndarray,
+                   n_local: int) -> jnp.ndarray:
+    """SwiGLU of each received row [S, D] through its own local expert,
+    empty slots zero: the rows sorted by expert, one grouped matmul per
+    projection, back to slot order."""
+    order, sizes = _local_groups(recv_meta, n_local)
+    xs = rows[order]
+    g = ops.gmm(xs, p["wi_gate"].astype(rows.dtype), sizes)
+    u = ops.gmm(xs, p["wi_up"].astype(rows.dtype), sizes)
+    ys = ops.gmm(jax.nn.silu(g) * u, p["wo"].astype(rows.dtype), sizes)
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.size, dtype=order.dtype))
+    # Rows past sum(sizes), where the empty slots sort, are undefined.
+    return jnp.where(recv_meta.reshape(-1, 1) > 0, ys[back], 0)
+
+
 def moe_block_ep(p, cfg: ModelConfig, x: jnp.ndarray, axis_name: str,
                  plan=None, overlap_compute=None):
     """Expert-parallel MoE inside ``shard_map`` over ``axis_name``.
@@ -215,18 +249,10 @@ def moe_block_ep(p, cfg: ModelConfig, x: jnp.ndarray, axis_name: str,
         recv_meta = lax.all_to_all(send_meta, axis_name, split_axis=0,
                                    concat_axis=0, tiled=True)
 
-    # ---- local expert compute (masked batched FFN over local experts) ---
+    # ---- local experts: each received row through its own expert -------
     with scope(EP_EXPERTS):
-        recv_flat = recv.reshape(ep * C, D)
-        eid = (recv_meta.reshape(-1) - 1)              # -1 = empty
-        buf = jnp.zeros((E_loc, ep * C, D), x.dtype)
-        sel = jax.nn.one_hot(eid, E_loc, dtype=x.dtype)  # [ep*C, E_loc]
-        buf = jnp.einsum("te,td->etd", sel, recv_flat)
-        g = jnp.einsum("etd,edf->etf", buf, p["wi_gate"].astype(x.dtype))
-        u = jnp.einsum("etd,edf->etf", buf, p["wi_up"].astype(x.dtype))
-        h = jax.nn.silu(g) * u
-        out_local = jnp.einsum("etf,efd->etd", h, p["wo"].astype(x.dtype))
-        out_flat = jnp.einsum("etd,te->td", out_local, sel)
+        out_flat = _local_experts(p, recv.reshape(ep * C, D), recv_meta,
+                                  E_loc)
 
     # ---- combine all-to-all back ----------------------------------------
     with scope(EP_COMBINE):

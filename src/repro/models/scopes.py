@@ -35,7 +35,8 @@ MOE_COMBINE = "moe.combine"    # gather back from the buffer, weighted sum
 EP_ROUTE = "ep.route"          # expert parallel: routing, destination slots
 EP_DISPATCH = "ep.dispatch"    # send buffer and the dispatch all-to-all
 EP_META = "ep.meta"            # slot metadata and its all-to-all
-EP_EXPERTS = "ep.experts"      # one-hot buffer, local SwiGLU, back to rows
+EP_EXPERTS = "ep.experts"      # rows sorted by local expert, grouped-matmul
+#                                SwiGLU, back to slot order
 EP_COMBINE = "ep.combine"      # combine all-to-all, gather, weighted sum
 
 SCOPES = (EMBED, LAYER_SCAN, ATTN, SSM, FFN, UNEMBED,

@@ -2,21 +2,26 @@
 here, with no chip attached.
 
 Covers the four Pallas kernels at the shapes ``workloads/calibrate.py``
-measures (interpret mode off) and the serving steps of
-granite-moe-1b-a400m at its published widths.  Nothing runs, so nothing
-here says anything about results or times.
+measures (interpret mode off), the serving steps of
+granite-moe-1b-a400m at its published widths, and the expert-parallel
+step of the ``qwen3moe_ep4`` benchmark cell over the described 2x2
+host.  Nothing runs, so nothing here says anything about results or
+times.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every pytest-xdist worker
 imports this file.  The persistent compile cache is off around these
 compiles (an entry compiled for a described chip cannot be read back).
 """
+import json
 import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from repro import configs
 from repro.workloads.calibrate import (_CAP_EXPERTS, _CAP_FF, _CAP_HEADS,
@@ -24,6 +29,8 @@ from repro.workloads.calibrate import (_CAP_EXPERTS, _CAP_FF, _CAP_HEADS,
 
 V5E_HBM_BYTES = 16 * 2**30
 GRANITE = "granite-moe-1b-a400m"
+BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))), "benchmarks", "chip")
 
 
 @pytest.fixture(scope="module")
@@ -138,3 +145,64 @@ def test_granite_full_width_decode_compiles_for_v5e(chip):
     ma = _fits_one_chip(decode.lower(params, tok, _on(chip, caches))
                         .compile())
     assert ma.alias_size_in_bytes > 0                # the cache is donated
+
+
+def test_qwen3_ep_step_runs_each_row_through_its_own_expert(topo, chip,
+                                                            monkeypatch):
+    """The 4-layer expert-parallel step at the ``qwen3moe_ep4`` cell's
+    widths (32 experts and 256 tokens per chip): no one-hot expert buffer
+    is left, the compiled work stays within twice what the model needs,
+    and every grouped-matmul kernel is timed under ``ep.experts``."""
+    from benchmarks.chip.scopes import scope_map
+    from benchmarks.chip.work import ep_step_work
+    from repro.kernels import ops
+    from repro.models import moe
+    from repro.models.base import ParamBuilder
+    from repro.models.scopes import EP_EXPERTS, SCOPES
+
+    with open(os.path.join(BENCH, "configs",
+                           "qwen3-moe-235b-a22b-ep4.json")) as f:
+        c = json.load(f)
+    with open(os.path.join(BENCH, "traffic", "ep_decode256.json")) as f:
+        tokens = json.load(f)["tokens_per_chip"]
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)   # compile, not interpret
+    cfg = configs.get_config(c["arch"]).replace(**c["overrides"])
+    n, L = len(topo.devices), cfg.n_layers
+    mesh = Mesh(np.array(topo.devices), ("model",))
+    e = P("model", None, None)
+    spec = {"router": P(), "wi_gate": e, "wi_up": e, "wo": e}
+
+    def one_layer(key):
+        b = ParamBuilder(key, cfg.dtype)
+        moe.init_moe(b, cfg, "moe")
+        return b.params["moe"]
+
+    layer = jax.eval_shape(one_layer, jax.random.PRNGKey(0))
+    params = {f"l{i}": {k: jax.ShapeDtypeStruct(
+        v.shape, v.dtype, sharding=NamedSharding(mesh, spec[k]))
+        for k, v in layer.items()} for i in range(L)}
+    x = jax.ShapeDtypeStruct((n * tokens, cfg.d_model), jnp.dtype(cfg.dtype),
+                             sharding=NamedSharding(mesh, P("model", None)))
+
+    def stack(p, x):
+        for i in range(L):
+            x = x + moe.moe_block_ep(p[f"l{i}"], cfg, x, "model")[0]
+        return x
+
+    compiled = jax.jit(jax.shard_map(
+        stack, mesh=mesh, in_specs=({f"l{i}": spec for i in range(L)},
+                                    P("model", None)),
+        out_specs=P("model", None), check_vma=False)).lower(
+            params, x).compile()
+    text = compiled.as_text()
+    e_loc = cfg.n_experts // n
+    slots = n * moe._capacity(cfg, tokens) * e_loc
+    assert f"[{e_loc},{slots},{cfg.d_model}]" not in text   # [32,2560,4096]
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["flops"] <= 2 * ep_step_work(c, tokens, n)["flops"]
+    scopes = scope_map(text, SCOPES)
+    kernels = [ln.split(" = ", 1)[0].split()[-1] for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(kernels) == 3 * L                      # gate, up, down
+    assert {scopes[k] for k in kernels} == {EP_EXPERTS}
