@@ -24,5 +24,15 @@ CONFIG = ModelConfig(
 )
 
 
+def share(n_held: int, offset: int, cfg: ModelConfig = CONFIG) -> ModelConfig:
+    """One expert-parallel rank of ``cfg``: every layer's router keeps all
+    its experts, and the experts ``[offset, offset + n_held)`` are held
+    here (EP16 holds 8 a rank)."""
+    if not (0 <= offset and offset + n_held <= cfg.n_experts):
+        raise ValueError(f"experts [{offset}, {offset + n_held}) are not "
+                         f"among {cfg.n_experts}")
+    return cfg.replace(experts_held=n_held, expert_offset=offset)
+
+
 def smoke() -> ModelConfig:
     return reduce_config(CONFIG, n_kv_heads=2)

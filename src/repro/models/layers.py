@@ -171,14 +171,37 @@ def attention(p, cfg: ModelConfig, x: jnp.ndarray, *, causal: bool = True,
     return with_logical(out, ("batch", "seq", "embed"))
 
 
-def attention_prefill(p, cfg: ModelConfig, x: jnp.ndarray, s_max: int, *,
-                      window: int = 0) -> Tuple[jnp.ndarray, KVCache]:
-    """Causal prefill that also returns a KV cache padded to ``s_max``."""
+# Prefill bounds its float32 score blocks [sequences, H, q chunk, S] to
+# this many bytes: past it, attention runs over groups of sequences.
+PREFILL_SCORE_BYTES = 1 << 30
+
+
+def _prefill_attn(p, cfg: ModelConfig, x: jnp.ndarray, window: int):
+    """Causal attention over whole sequences x: [B,S,D] -> (out, k, v)."""
     B, S, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     q, k, v = _project_qkv(p, cfg, x, positions, rope=True)
     out = _blocked_sdpa(cfg, q, k, v, causal=True, window=window)
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
+    return out, k, v
+
+
+def attention_prefill(p, cfg: ModelConfig, x: jnp.ndarray, s_max: int, *,
+                      window: int = 0) -> Tuple[jnp.ndarray, KVCache]:
+    """Causal prefill that also returns a KV cache padded to ``s_max``.
+    A batch whose score blocks would pass ``PREFILL_SCORE_BYTES`` goes
+    through attention a group of sequences at a time."""
+    B, S, D = x.shape
+    per_seq = cfg.n_heads * min(Q_CHUNK, S) * S * 4
+    g = max(1, min(B, PREFILL_SCORE_BYTES // per_seq))
+    while B % g:
+        g -= 1
+    if g == B:
+        out, k, v = _prefill_attn(p, cfg, x, window)
+    else:
+        out, k, v = lax.map(lambda xg: _prefill_attn(p, cfg, xg, window),
+                            x.reshape(B // g, g, S, D))
+        out, k, v = (a.reshape(B, *a.shape[2:]) for a in (out, k, v))
     KVh, Dh = cfg.n_kv_heads, cfg.d_head
     pad = s_max - S
     kc = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))

@@ -15,7 +15,12 @@
                   runs each row through its own expert only, with a
                   grouped matmul (``kernels.ops.gmm``).
 
-Both paths share routing; both drop tokens beyond capacity (GShard-style)
+``moe_held``    — one expert-parallel rank's share on one chip: routes over
+                  every expert, and runs the choices that land on the
+                  experts held here through the same sorted-row grouped
+                  matmul, with no exchange and no capacity (dropless).
+
+The first two share routing and drop tokens beyond capacity (GShard-style)
 with residual passthrough.
 """
 from __future__ import annotations
@@ -33,9 +38,12 @@ from .scopes import (EP_COMBINE, EP_DISPATCH, EP_EXPERTS, EP_META, EP_ROUTE,
 
 
 def init_moe(b: ParamBuilder, cfg: ModelConfig, name: str = "moe"):
+    """The router over all ``n_experts``; the weights of the experts held
+    here (``experts_held`` of them, or all)."""
     m = b.child(name)
-    D, E, F = cfg.d_model, cfg.n_experts, cfg.d_ff_expert
-    m.normal("router", (D, E), ("embed", None), fan_in=D)
+    D, F = cfg.d_model, cfg.d_ff_expert
+    m.normal("router", (D, cfg.n_experts), ("embed", None), fan_in=D)
+    E = cfg.experts_held or cfg.n_experts
     m.normal("wi_gate", (E, D, F), ("experts", "expert_embed", "expert_mlp"),
              fan_in=D)
     m.normal("wi_up", (E, D, F), ("experts", "expert_embed", "expert_mlp"),
@@ -179,20 +187,89 @@ def _local_groups(recv_meta: jnp.ndarray, n_local: int):
     return order, sizes.astype(jnp.int32)
 
 
+def _grouped_swiglu(p, xs: jnp.ndarray, sizes: jnp.ndarray) -> jnp.ndarray:
+    """SwiGLU of rows [S, D] sorted by local expert (``sizes`` rows each),
+    one grouped matmul per projection.  Rows past ``sum(sizes)`` come out
+    undefined."""
+    g = ops.gmm(xs, p["wi_gate"].astype(xs.dtype), sizes)
+    u = ops.gmm(xs, p["wi_up"].astype(xs.dtype), sizes)
+    return ops.gmm(jax.nn.silu(g) * u, p["wo"].astype(xs.dtype), sizes)
+
+
+def _slot_order(ys: jnp.ndarray, order: jnp.ndarray,
+                meta: jnp.ndarray) -> jnp.ndarray:
+    """Sorted rows back to slot order, the empty slots (``meta`` 0, sorted
+    past the filled rows, so undefined) zero."""
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.size, dtype=order.dtype))
+    return jnp.where(meta.reshape(-1, 1) > 0, ys[back], 0)
+
+
 def _local_experts(p, rows: jnp.ndarray, recv_meta: jnp.ndarray,
                    n_local: int) -> jnp.ndarray:
     """SwiGLU of each received row [S, D] through its own local expert,
     empty slots zero: the rows sorted by expert, one grouped matmul per
     projection, back to slot order."""
     order, sizes = _local_groups(recv_meta, n_local)
-    xs = rows[order]
-    g = ops.gmm(xs, p["wi_gate"].astype(rows.dtype), sizes)
-    u = ops.gmm(xs, p["wi_up"].astype(rows.dtype), sizes)
-    ys = ops.gmm(jax.nn.silu(g) * u, p["wo"].astype(rows.dtype), sizes)
-    back = jnp.zeros_like(order).at[order].set(
-        jnp.arange(order.size, dtype=order.dtype))
-    # Rows past sum(sizes), where the empty slots sort, are undefined.
-    return jnp.where(recv_meta.reshape(-1, 1) > 0, ys[back], 0)
+    return _slot_order(_grouped_swiglu(p, rows[order], sizes), order,
+                       recv_meta)
+
+
+def _held_block(p, x: jnp.ndarray, meta: jnp.ndarray, w: jnp.ndarray,
+                n_held: int) -> jnp.ndarray:
+    """One block of tokens through the held experts.  ``x`` [T, D];
+    ``meta`` [T, k], each choice's held expert + 1 (0 = held elsewhere);
+    ``w`` [T, k] the router's weights.  Returns [T, D], each token's
+    weighted sum over its held choices."""
+    T, k = meta.shape
+    with scope(MOE_DISPATCH):
+        order, sizes = _local_groups(meta, n_held)
+        xs = x[order // k]
+    with scope(MOE_EXPERTS):
+        ys = _grouped_swiglu(p, xs, sizes)
+    with scope(MOE_COMBINE):
+        out = _slot_order(ys, order, meta).reshape(T, k, -1)
+        return (out * w[..., None]).sum(axis=1)
+
+
+# Tokens per block of the held-expert layer: at most this many times
+# top_k rows (and their SwiGLU) are live at once, whatever the routing.
+HELD_BLOCK_TOKENS = 8192
+
+
+def moe_held(p, cfg: ModelConfig, x: jnp.ndarray):
+    """MoE FFN of one expert-parallel rank for [B,S,D] input: routes over
+    all ``n_experts`` and adds what the ``experts_held`` experts from
+    ``expert_offset`` give; choices of experts held elsewhere add nothing
+    here.  Dropless: every held choice is computed.  Tokens go through the
+    experts in blocks of ``HELD_BLOCK_TOKENS`` (the last one padded)."""
+    B, S, D = x.shape
+    T, k, n = B * S, cfg.top_k, cfg.experts_held
+    xf = x.reshape(T, D)
+    with scope(MOE_ROUTER):
+        idx, w, aux = route(p, cfg, xf)
+    with scope(MOE_DISPATCH):
+        local = idx - cfg.expert_offset
+        meta = jnp.where((local >= 0) & (local < n), local + 1, 0)
+    tb = min(T, HELD_BLOCK_TOKENS)
+    nb = -(-T // tb)
+    if nb == 1:
+        y = _held_block(p, xf, meta, w, n)
+    else:
+        pad = nb * tb - T
+        blocks = [jnp.pad(a, ((0, pad), (0, 0))).reshape(nb, tb, -1)
+                  for a in (xf, meta, w)]
+        y = lax.map(lambda a: _held_block(p, *a, n), blocks)
+        y = y.reshape(nb * tb, D)[:T]
+    return y.reshape(B, S, D), aux
+
+
+def moe_ffn(p, cfg: ModelConfig, x: jnp.ndarray):
+    """The MoE FFN a config serves: its held share of the experts
+    (``moe_held``) where it holds one, else every expert (``moe_gather``)."""
+    if cfg.experts_held:
+        return moe_held(p, cfg, x)
+    return moe_gather(p, cfg, x)
 
 
 def moe_block_ep(p, cfg: ModelConfig, x: jnp.ndarray, axis_name: str,

@@ -44,6 +44,11 @@ class ModelConfig:
     moe_every: int = 1               # MoE FFN on layers where idx % every == r
     capacity_factor: float = 1.25
     moe_impl: str = "gather"         # "gather" (pjit auto) | "ep" (shard_map)
+    # One expert-parallel rank's share, served on one chip: the experts
+    # [expert_offset, expert_offset + experts_held) of the router's
+    # n_experts are held here (0 = all of them, through moe_gather).
+    experts_held: int = 0
+    expert_offset: int = 0
     # SSM / hybrid
     layer_pattern: Tuple[str, ...] = ()   # repeating pattern, e.g. 7x mamba + attn
     ssm_state: int = 0

@@ -21,7 +21,7 @@ from jax import lax
 from .base import ModelConfig, ParamBuilder, stacked_specs, with_logical
 from . import layers as L
 from .layers import KVCache
-from .moe import init_moe, moe_gather
+from .moe import init_moe, moe_ffn
 from .scopes import ATTN, EMBED, FFN, LAYER_SCAN, SSM, UNEMBED, scope
 from .ssd import SSMCache, init_ssm, ssm_layer, ssm_prefill, ssm_decode, ssm_dims
 
@@ -104,7 +104,7 @@ def _layer_forward(cfg: ModelConfig, kind: str, pos: int, p, x):
     if _has_ffn(cfg):
         h = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
         if _layer_is_moe(cfg, pos):
-            h, a = moe_gather(p["moe"], cfg, h)
+            h, a = moe_ffn(p["moe"], cfg, h)
             aux = aux + a
         else:
             h = L.mlp(p["mlp"], h, n_chunks=cfg.ffn_chunks)
@@ -222,7 +222,7 @@ def _block_prefill(cfg: ModelConfig, bp, x, s_max: int):
             with scope(FFN):
                 h = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
                 if _layer_is_moe(cfg, pos):
-                    h, _ = moe_gather(p["moe"], cfg, h)
+                    h, _ = moe_ffn(p["moe"], cfg, h)
                 else:
                     h = L.mlp(p["mlp"], h, n_chunks=cfg.ffn_chunks)
                 x = x + h
@@ -247,7 +247,7 @@ def _block_decode(cfg: ModelConfig, bp, x, caches):
             with scope(FFN):
                 h = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
                 if _layer_is_moe(cfg, pos):
-                    h, _ = moe_gather(p["moe"], cfg, h)
+                    h, _ = moe_ffn(p["moe"], cfg, h)
                 else:
                     h = L.mlp(p["mlp"], h, n_chunks=cfg.ffn_chunks)
                 x = x + h

@@ -206,3 +206,44 @@ def test_qwen3_ep_step_runs_each_row_through_its_own_expert(topo, chip,
                if 'custom_call_target="tpu_custom_call"' in ln]
     assert len(kernels) == 3 * L                      # gate, up, down
     assert {scopes[k] for k in kernels} == {EP_EXPERTS}
+
+
+def test_qwen3_share_serving_steps_compile_for_v5e(chip, monkeypatch):
+    """The ``qwen3_share_decode`` cell's prefill (64 prompts of 2048) and
+    decode step (caches of 2304) at published widths, 8 layers and 8 held
+    experts: each fits one chip, and the held experts' grouped matmuls
+    (gate, up, down) are the only kernels, all timed under
+    ``moe.experts``."""
+    from benchmarks.chip.scopes import scope_map
+    from repro.kernels import ops
+    from repro.launch.serve import make_steps, serving_config
+    from repro.models import api
+    from repro.models.scopes import MOE_EXPERTS, SCOPES
+
+    with open(os.path.join(BENCH, "configs",
+                           "qwen3-moe-235b-a22b-ep16.json")) as f:
+        c = json.load(f)
+    with open(os.path.join(BENCH, "traffic", "long_prompt_rounds.json")) as f:
+        t = json.load(f)
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)   # compile, not interpret
+    cfg = serving_config(configs.get_config(c["arch"]).replace(
+        **c["overrides"]))
+    assert (cfg.n_experts, cfg.experts_held, cfg.d_model) == (128, 8, 4096)
+    B, P = t["batch"], t["prompt_len"]
+    params = _on(chip, jax.eval_shape(lambda k: api.init(cfg, k)[0],
+                                      jax.random.PRNGKey(0)))
+    batch = {"inputs": jax.ShapeDtypeStruct((B, P), jnp.int32,
+                                            sharding=chip)}
+    prefill, decode = make_steps(cfg, P + t["gen_tokens"])
+    _, caches = jax.eval_shape(prefill, params, batch)
+    tok = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=chip)
+    for compiled in (prefill.lower(params, batch).compile(),
+                     decode.lower(params, tok, _on(chip, caches)).compile()):
+        _fits_one_chip(compiled)
+        text = compiled.as_text()
+        scopes = scope_map(text, SCOPES)
+        kernels = [ln.split(" = ", 1)[0].split()[-1]
+                   for ln in text.splitlines()
+                   if 'custom_call_target="tpu_custom_call"' in ln]
+        assert len(kernels) == 3                   # in the scanned layer
+        assert {scopes[k] for k in kernels} == {MOE_EXPERTS}
