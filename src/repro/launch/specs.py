@@ -46,8 +46,8 @@ def empty_caches(cfg: ModelConfig, batch: int, s_max: int):
     if cfg.is_encoder_decoder:
         L = cfg.n_layers
         kv = KVCache(
-            k=jnp.zeros((L, batch, s_max, cfg.n_kv_heads, cfg.d_head), cfg.dtype),
-            v=jnp.zeros((L, batch, s_max, cfg.n_kv_heads, cfg.d_head), cfg.dtype),
+            k=jnp.zeros((L, batch, cfg.n_kv_heads, s_max, cfg.d_head), cfg.dtype),
+            v=jnp.zeros((L, batch, cfg.n_kv_heads, s_max, cfg.d_head), cfg.dtype),
             length=jnp.full((L,), 0, jnp.int32))
         cross = jnp.zeros((L, batch, cfg.enc_frames, cfg.n_kv_heads,
                            cfg.d_head), cfg.dtype)

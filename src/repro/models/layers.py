@@ -44,8 +44,11 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float):
 
 # --------------------------------------------------------------- Attention
 class KVCache(NamedTuple):
-    k: jnp.ndarray       # [B, S_max, KV, Dh]
-    v: jnp.ndarray       # [B, S_max, KV, Dh]
+    """One layer's cache, or (with a leading blocks axis on every leaf) the
+    stack that ``transformer.decode_step`` updates in place.  Each kv head's
+    ``[S_max, Dh]`` is contiguous, the order the decode dots read."""
+    k: jnp.ndarray       # [B, KV, S_max, Dh]
+    v: jnp.ndarray       # [B, KV, S_max, Dh]
     length: jnp.ndarray  # [] int32 current fill
 
 
@@ -86,7 +89,19 @@ def _project_qkv(p, cfg: ModelConfig, x: jnp.ndarray, positions, rope: bool):
 def _sdpa(cfg: ModelConfig, q, k, v, mask) -> jnp.ndarray:
     """q: [B,Sq,H,Dh]; k,v: [B,Sk,KV,Dh]; mask: [B,1,Sq,Sk] or None."""
     B, Sq, H, Dh = q.shape
-    KV = k.shape[2]
+    qg = _grouped_q(q, k.shape[2])
+    logits = jnp.einsum("bqkgd,bskd->bkgqs", qg, k).astype(jnp.float32)
+    logits *= 1.0 / math.sqrt(Dh)
+    if mask is not None:
+        logits = jnp.where(mask[:, :, None], logits, -1e30)
+    w = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+    out = jnp.einsum("bkgqs,bskd->bqkgd", w, v)
+    return out.reshape(B, Sq, H, Dh)
+
+
+def _grouped_q(q, KV: int):
+    """q: [B,Sq,H,Dh] -> [B,Sq,KV,group,Dh], the query heads of each kv head."""
+    B, Sq, H, Dh = q.shape
     group = H // KV
     qg = q.reshape(B, Sq, KV, group, Dh)
     # Give the grouped-head reshape a coherent layout when KV or group
@@ -100,13 +115,7 @@ def _sdpa(cfg: ModelConfig, q, k, v, mask) -> jnp.ndarray:
     if KV % ms == 0 or group % ms == 0:
         qg = with_logical(qg, ("batch", None, "kv_heads", "heads", None),
                           partial=True)
-    logits = jnp.einsum("bqkgd,bskd->bkgqs", qg, k).astype(jnp.float32)
-    logits *= 1.0 / math.sqrt(Dh)
-    if mask is not None:
-        logits = jnp.where(mask[:, :, None], logits, -1e30)
-    w = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
-    out = jnp.einsum("bkgqs,bskd->bqkgd", w, v)
-    return out.reshape(B, Sq, H, Dh)
+    return qg
 
 
 def _causal_mask(Sq: int, Sk: int, window: int = 0,
@@ -202,35 +211,64 @@ def attention_prefill(p, cfg: ModelConfig, x: jnp.ndarray, s_max: int, *,
         out, k, v = lax.map(lambda xg: _prefill_attn(p, cfg, xg, window),
                             x.reshape(B // g, g, S, D))
         out, k, v = (a.reshape(B, *a.shape[2:]) for a in (out, k, v))
-    KVh, Dh = cfg.n_kv_heads, cfg.d_head
-    pad = s_max - S
-    kc = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    vc = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    cache = KVCache(k=with_logical(kc, ("batch", "cache_seq", "kv_heads", "head_dim")),
-                    v=with_logical(vc, ("batch", "cache_seq", "kv_heads", "head_dim")),
-                    length=jnp.array(S, jnp.int32))
-    return out, cache
+    pad = ((0, 0), (0, 0), (0, s_max - S), (0, 0))
+    kc, vc = (with_logical(jnp.pad(a.swapaxes(1, 2), pad),
+                           ("batch", "kv_heads", "cache_seq", "head_dim"))
+              for a in (k, v))
+    return out, KVCache(k=kc, v=vc, length=jnp.array(S, jnp.int32))
+
+
+def attention_decode_at(p, cfg: ModelConfig, x: jnp.ndarray, caches: KVCache,
+                        i, *, window: int = 0) -> Tuple[jnp.ndarray, KVCache]:
+    """Single-token decode on layer ``i`` of stacked caches (every leaf
+    with a leading blocks axis).  x: [B,1,D].  Layer ``i`` is read where
+    it lies, before the token goes in: its positions ``< length[i]``
+    (inside the window) and the token's own k, v share one softmax.  The
+    token's k, v are then written in place at ``[i, :, :, length[i]]``.
+    (Read after the write, in a scan, the dots set the carried stack's
+    layout and XLA copies the whole stack into and out of the loop.)"""
+    B = x.shape[0]
+    length = caches.length[i]
+    q, k, v = _project_qkv(p, cfg, x, jnp.broadcast_to(length, (B, 1)),
+                           rope=True)
+    k = k.swapaxes(1, 2).astype(caches.k.dtype)          # [B, KV, 1, Dh]
+    v = v.swapaxes(1, 2).astype(caches.v.dtype)
+    k_old = lax.dynamic_index_in_dim(caches.k, i, keepdims=False)
+    v_old = lax.dynamic_index_in_dim(caches.v, i, keepdims=False)
+    KV, S_max, Dh = k_old.shape[1:]
+    qg = _grouped_q(q, KV)
+
+    def scores(kc):
+        s = jnp.einsum("bqkgd,bksd->bkgqs", qg, kc).astype(jnp.float32)
+        return s * (1.0 / math.sqrt(Dh))
+
+    j = jnp.arange(S_max)
+    valid = j < length
+    if window > 0:
+        valid &= j > length - window
+    logits = jnp.concatenate([jnp.where(valid, scores(k_old), -1e30),
+                              scores(k)], axis=-1)
+    w = jax.nn.softmax(logits, axis=-1).astype(v_old.dtype)
+    f32 = jnp.float32
+    out = (jnp.einsum("bkgqs,bksd->bqkgd", w[..., :S_max], v_old).astype(f32)
+           + jnp.einsum("bkgqs,bksd->bqkgd", w[..., S_max:].astype(f32),
+                        v.astype(f32)))
+    out = out.astype(x.dtype).reshape(q.shape)
+    out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
+    at = (i, 0, 0, length, 0)
+    return out, KVCache(k=lax.dynamic_update_slice(caches.k, k[None], at),
+                        v=lax.dynamic_update_slice(caches.v, v[None], at),
+                        length=caches.length.at[i].add(1))
 
 
 def attention_decode(p, cfg: ModelConfig, x: jnp.ndarray, cache: KVCache, *,
                      window: int = 0) -> Tuple[jnp.ndarray, KVCache]:
-    """Single-token decode.  x: [B,1,D]; appends to cache at ``length``."""
-    B = x.shape[0]
-    pos = jnp.broadcast_to(cache.length, (B, 1))
-    q, k, v = _project_qkv(p, cfg, x, pos, rope=True)
-    kc = lax.dynamic_update_slice_in_dim(cache.k, k.astype(cache.k.dtype),
-                                         cache.length, axis=1)
-    vc = lax.dynamic_update_slice_in_dim(cache.v, v.astype(cache.v.dtype),
-                                         cache.length, axis=1)
-    S_max = kc.shape[1]
-    j = jnp.arange(S_max)
-    valid = j <= cache.length
-    if window > 0:
-        valid &= j > cache.length - window
-    mask = jnp.broadcast_to(valid[None, None, None, :], (B, 1, 1, S_max))
-    out = _sdpa(cfg, q, kc, vc, mask)
-    out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
-    return out, KVCache(k=kc, v=vc, length=cache.length + 1)
+    """Single-token decode on one layer's cache.  x: [B,1,D]; appends to
+    the cache at ``length``."""
+    out, new = attention_decode_at(p, cfg, x,
+                                   jax.tree.map(lambda a: a[None], cache), 0,
+                                   window=window)
+    return out, jax.tree.map(lambda a: a[0], new)
 
 
 def cross_attention(p, cfg: ModelConfig, x: jnp.ndarray, enc_k, enc_v):

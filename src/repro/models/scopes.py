@@ -22,9 +22,9 @@ def scope(name: str):
 
 
 EMBED = "embed"                # token embedding lookup (and image prefix)
-LAYER_SCAN = "layer_scan"      # loop over blocks: per-layer weight and cache
-#                                slices, the stacked new cache
-ATTN = "attn"                  # ln1, attention with its cache update, residual
+LAYER_SCAN = "layer_scan"      # loop over blocks: per-layer weight slices
+ATTN = "attn"                  # ln1, attention with its in-place cache write,
+#                                residual
 SSM = "ssm"                    # ln1, SSM mixer with its state update, residual
 FFN = "ffn"                    # ln2, dense or MoE FFN, residual
 UNEMBED = "unembed"            # final norm, logits, greedy next token

@@ -12,7 +12,7 @@ decode caches), ``decode_step`` (single token with caches).
 from __future__ import annotations
 
 import functools
-from typing import Dict, NamedTuple, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -179,12 +179,6 @@ def loss_fn(cfg: ModelConfig, params, batch: Dict[str, jnp.ndarray]):
 
 
 # ------------------------------------------------------------------- decode
-class LayerCache(NamedTuple):
-    """Union cache for one layer position of a block (attn or ssm slots)."""
-    kv: Optional[KVCache]
-    ssm: Optional[SSMCache]
-
-
 def _empty_caches(cfg: ModelConfig, batch: int, s_max: int):
     """Per-block cache pytree (stacked over blocks by the caller)."""
     caches = {}
@@ -193,8 +187,8 @@ def _empty_caches(cfg: ModelConfig, batch: int, s_max: int):
     for pos, kind in enumerate(cfg.pattern):
         if kind == "attn":
             kv = KVCache(
-                k=jnp.zeros((batch, s_max, cfg.n_kv_heads, cfg.d_head), cfg.dtype),
-                v=jnp.zeros((batch, s_max, cfg.n_kv_heads, cfg.d_head), cfg.dtype),
+                k=jnp.zeros((batch, cfg.n_kv_heads, s_max, cfg.d_head), cfg.dtype),
+                v=jnp.zeros((batch, cfg.n_kv_heads, s_max, cfg.d_head), cfg.dtype),
                 length=jnp.zeros((), jnp.int32))
             caches[f"l{pos}"] = kv
         else:
@@ -229,19 +223,26 @@ def _block_prefill(cfg: ModelConfig, bp, x, s_max: int):
     return x, caches
 
 
-def _block_decode(cfg: ModelConfig, bp, x, caches):
-    new = {}
+def _block_decode(cfg: ModelConfig, bp, x, caches, i):
+    """Block ``i`` of the stacked caches: attention writes its token into
+    the stack in place; an SSM layer replaces its own state at ``i``."""
+    caches = dict(caches)
     for pos, kind in enumerate(cfg.pattern):
         p = bp[f"l{pos}"]
+        c = caches[f"l{pos}"]
         with scope(ATTN if kind == "attn" else SSM):
             h = L.rmsnorm(x, p["ln1"], cfg.norm_eps)
             if kind == "attn":
-                h, c = L.attention_decode(p["attn"], cfg, h,
-                                          caches[f"l{pos}"],
-                                          window=cfg.sliding_window)
+                h, c = L.attention_decode_at(p["attn"], cfg, h, c, i,
+                                             window=cfg.sliding_window)
             else:
-                h, c = ssm_decode(p["ssm"], cfg, h, caches[f"l{pos}"])
-            new[f"l{pos}"] = c
+                h, new = ssm_decode(p["ssm"], cfg, h, jax.tree.map(
+                    lambda v: lax.dynamic_index_in_dim(v, i, keepdims=False),
+                    c))
+                c = jax.tree.map(
+                    lambda v, n: lax.dynamic_update_index_in_dim(v, n, i, 0),
+                    c, new)
+            caches[f"l{pos}"] = c
             x = x + h
         if _has_ffn(cfg):
             with scope(FFN):
@@ -251,7 +252,7 @@ def _block_decode(cfg: ModelConfig, bp, x, caches):
                 else:
                     h = L.mlp(p["mlp"], h, n_chunks=cfg.ffn_chunks)
                 x = x + h
-    return x, new
+    return x, caches
 
 
 def prefill(cfg: ModelConfig, params, tokens: jnp.ndarray, s_max: int,
@@ -284,26 +285,26 @@ def prefill(cfg: ModelConfig, params, tokens: jnp.ndarray, s_max: int,
 
 
 def decode_step(cfg: ModelConfig, params, token: jnp.ndarray, caches):
-    """token: [B] -> (logits [B,V], new caches).  Caches stacked over blocks."""
+    """token: [B] -> (logits [B,V], new caches).  The caches, stacked over
+    blocks, are carried through the blocks and written in place: block
+    ``i`` adds one token per attention layer at ``[i, :, :, length[i]]``,
+    so a donated cache comes back in the same buffers."""
     with scope(EMBED):
         x = L.embed(params, cfg, token[:, None])
 
-    def step(x, bc):
-        bp, cache = bc
-        x, new = _block_decode(cfg, bp, x, cache)
-        return x, new
-
     with scope(LAYER_SCAN):
         if cfg.scan_layers and cfg.n_blocks > 1:
-            x, new_caches = lax.scan(step, x, (params["blocks"], caches))
+            def step(carry, bi):
+                bp, i = bi
+                return _block_decode(cfg, bp, *carry, i), None
+
+            (x, caches), _ = lax.scan(
+                step, (x, caches),
+                (params["blocks"], jnp.arange(cfg.n_blocks)))
         else:
-            nl = []
             for i in range(cfg.n_blocks):
                 bp = jax.tree.map(lambda v: v[i], params["blocks"])
-                cache = jax.tree.map(lambda v: v[i], caches)
-                x, c = step(x, (bp, cache))
-                nl.append(c)
-            new_caches = jax.tree.map(lambda *xs: jnp.stack(xs), *nl)
+                x, caches = _block_decode(cfg, bp, x, caches, i)
     with scope(UNEMBED):
         logits = L.unembed(params, cfg, x)[:, 0]
-    return logits, new_caches
+    return logits, caches

@@ -129,7 +129,7 @@ def cache_pspecs(cfg, cache_shapes, rules) -> Any:
     """PartitionSpecs for a decode-cache pytree (by leaf shape pattern).
 
     Caches are built by ``api.prefill``: KVCache leaves are
-    [blocks, B, S, KV, Dh], SSM conv [blocks, B, K-1, C], SSM state
+    [blocks, B, KV, S, Dh], SSM conv [blocks, B, K-1, C], SSM state
     [blocks, B, H, P, N], lengths [blocks]; enc-dec cross-KV are
     [blocks, B, F, KV, Dh].  We map axes by position.
     """
@@ -140,7 +140,7 @@ def cache_pspecs(cfg, cache_shapes, rules) -> Any:
 
     def spec_for(leaf):
         nd = len(leaf.shape)
-        if nd == 5:                      # [L, B, S, KV, Dh]
+        if nd == 5:                      # enc-dec cross-KV [L, B, F, KV, Dh]
             return P(None, data, cseq, kv, hd)
         if nd == 4:                      # [L, B, K-1, x|B|C] conv cache
             # channel dim replicated: it concatenates a sharded (x) and two
@@ -164,8 +164,8 @@ def cache_pspecs(cfg, cache_shapes, rules) -> Any:
 
     def map_cache(c):
         if isinstance(c, KVCache):
-            return KVCache(k=spec_for(c.k), v=spec_for(c.v),
-                           length=P(None))
+            kv_spec = P(None, data, kv, cseq, hd)    # [L, B, KV, S, Dh]
+            return KVCache(k=kv_spec, v=kv_spec, length=P(None))
         if isinstance(c, SSMCache):
             return SSMCache(conv=spec_for(c.conv),
                             state=spec_for_state(c.state))

@@ -72,8 +72,8 @@ class TestCachePspecs:
     def test_kv_cache_decode(self):
         rules = rules_for(WorkloadKind.DECODE)
         kv = KVCache(
-            k=jax.ShapeDtypeStruct((8, 128, 32896, 8, 128), jnp.bfloat16),
-            v=jax.ShapeDtypeStruct((8, 128, 32896, 8, 128), jnp.bfloat16),
+            k=jax.ShapeDtypeStruct((8, 128, 8, 32896, 128), jnp.bfloat16),
+            v=jax.ShapeDtypeStruct((8, 128, 8, 32896, 128), jnp.bfloat16),
             length=jax.ShapeDtypeStruct((8,), jnp.int32))
         spec = cache_pspecs(None, {"l0": kv}, rules)["l0"]
         assert spec.k == P(None, ("data",), None, None, "model")
@@ -91,11 +91,11 @@ class TestCachePspecs:
     def test_long_decode_seq_sharded(self):
         rules = rules_for(WorkloadKind.LONG_DECODE)
         kv = KVCache(
-            k=jax.ShapeDtypeStruct((9, 1, 524416, 8, 128), jnp.bfloat16),
-            v=jax.ShapeDtypeStruct((9, 1, 524416, 8, 128), jnp.bfloat16),
+            k=jax.ShapeDtypeStruct((9, 1, 8, 524416, 128), jnp.bfloat16),
+            v=jax.ShapeDtypeStruct((9, 1, 8, 524416, 128), jnp.bfloat16),
             length=jax.ShapeDtypeStruct((9,), jnp.int32))
         spec = cache_pspecs(None, {"l0": kv}, rules)["l0"]
-        assert spec.k == P(None, None, ("data",), None, "model")
+        assert spec.k == P(None, None, None, ("data",), "model")
 
 
 class TestOverlapPrimitives:

@@ -15,6 +15,7 @@ compiles (an entry compiled for a described chip cannot be read back).
 """
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -147,6 +148,39 @@ def test_granite_full_width_decode_compiles_for_v5e(chip):
     assert ma.alias_size_in_bytes > 0                # the cache is donated
 
 
+def _cache_stays_in_place(compiled, caches):
+    """The decode step writes into its donated stacked cache where it lies:
+    no copy has the shape of a whole stacked K or V, and the step's
+    scratch stays under a quarter of the cache's bytes."""
+    ma = compiled.memory_analysis()
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(caches))
+    stacks = {"[" + ",".join(map(str, a.shape)) + "]"
+              for a in jax.tree.leaves(caches) if a.ndim == 5}
+    copies = [ln for ln in compiled.as_text().splitlines()
+              if re.search(r"\[[0-9,]+\]\{[^}]*\} copy(-start)?\(", ln)
+              and re.search(r"\[[0-9,]+\]", ln).group(0) in stacks]
+    assert not copies, copies
+    assert ma.temp_size_in_bytes < 0.25 * cache_bytes, (
+        ma.temp_size_in_bytes, cache_bytes)
+    assert ma.alias_size_in_bytes > 0                # the cache is donated
+
+
+def test_granite_decode_writes_its_cache_in_place_for_v5e(chip):
+    """The ``granite_decode`` cell's decode step (64 sequences, caches of
+    256 + 512 positions over 24 layers, 2.4 GB)."""
+    with open(os.path.join(BENCH, "traffic", "decode_rounds.json")) as f:
+        t = json.load(f)
+    B, P = t["batch"], t["prompt_len"]
+    _, params, prompts, prefill, decode = _granite_steps(
+        chip, B, P, P + t["gen_tokens"])
+    _, caches = jax.eval_shape(prefill, params, prompts)
+    tok = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=chip)
+    compiled = decode.lower(params, tok, _on(chip, caches)).compile()
+    _fits_one_chip(compiled)
+    _cache_stays_in_place(compiled, caches)
+
+
 def test_qwen3_ep_step_runs_each_row_through_its_own_expert(topo, chip,
                                                             monkeypatch):
     """The 4-layer expert-parallel step at the ``qwen3moe_ep4`` cell's
@@ -211,9 +245,9 @@ def test_qwen3_ep_step_runs_each_row_through_its_own_expert(topo, chip,
 def test_qwen3_share_serving_steps_compile_for_v5e(chip, monkeypatch):
     """The ``qwen3_share_decode`` cell's prefill (64 prompts of 2048) and
     decode step (caches of 2304) at published widths, 8 layers and 8 held
-    experts: each fits one chip, and the held experts' grouped matmuls
-    (gate, up, down) are the only kernels, all timed under
-    ``moe.experts``."""
+    experts: each fits one chip, the decode step writes its cache in
+    place, and the held experts' grouped matmuls (gate, up, down) are the
+    only kernels, all timed under ``moe.experts``."""
     from benchmarks.chip.scopes import scope_map
     from repro.kernels import ops
     from repro.launch.serve import make_steps, serving_config
@@ -237,8 +271,9 @@ def test_qwen3_share_serving_steps_compile_for_v5e(chip, monkeypatch):
     prefill, decode = make_steps(cfg, P + t["gen_tokens"])
     _, caches = jax.eval_shape(prefill, params, batch)
     tok = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=chip)
-    for compiled in (prefill.lower(params, batch).compile(),
-                     decode.lower(params, tok, _on(chip, caches)).compile()):
+    step = decode.lower(params, tok, _on(chip, caches)).compile()
+    _cache_stays_in_place(step, caches)
+    for compiled in (prefill.lower(params, batch).compile(), step):
         _fits_one_chip(compiled)
         text = compiled.as_text()
         scopes = scope_map(text, SCOPES)
