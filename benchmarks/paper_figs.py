@@ -564,8 +564,7 @@ def fig17_algorithm_selection() -> List[Row]:
     from repro.core.select import AutoPolicy, FixedPolicy, build_policy_table
     from repro.core.session import SimSession
 
-    base = SimConfig(translation=TranslationConfig(page_bytes=4 * KB),
-                     engine="vectorized")
+    base = SimConfig(translation=TranslationConfig(page_bytes=4 * KB))
     fab = FabricConfig(n_gpus=_FIG17_N)
     auto = AutoPolicy(base=base)
     rows = []
@@ -646,11 +645,9 @@ def fig17_algorithm_selection() -> List[Row]:
 # 4096-token cap (prompt_mean far above it, so ~80% of shards are the full
 # ~12.6MB and the decode pod's KV arena ring wraps within the stream —
 # transfers reach their steady-state regime), short outputs so the decode
-# pods drain within the step budget.  The vectorized engine is bit-for-bit
-# the event engine (proven by the tier-1 differential tests) and ~10x
-# faster for a benchmark this wide.
+# pods drain within the step budget.
 _FIG18_BASE = dict(arch="granite-moe-1b-a400m", n_requests=32, seed=7,
-                   prompt_mean=16384, output_mean=8, engine="vectorized")
+                   prompt_mean=16384, output_mean=8)
 _FIG18_TOPOS = {
     "single_clos": {},
     "two_tier": dict(topology="two_tier", leaf_size=8, oversubscription=2.0),

@@ -1,15 +1,13 @@
 # One function per paper table/figure. Prints ``name,us_per_call,derived``
-# CSV.  ``--bench-engine`` instead times a fixed sweep grid through BOTH
-# simulation engines (event and vectorized, which must agree bit-for-bit —
-# the bench doubles as a coarse differential check), emits the per-point
-# speedup column, and writes BENCH_engine.json (uploaded as a CI artifact
-# so the engines' performance trajectory is tracked PR over PR);
+# CSV.  ``--bench-engine`` instead times a fixed sweep grid through the
+# simulator and writes BENCH_engine.json (uploaded as a CI artifact so the
+# engine's performance trajectory is tracked PR over PR);
 # ``--check-against benchmarks/BENCH_baseline.json`` turns that grid into a
-# regression gate: any point whose wall time (either engine) exceeds the
-# committed baseline by more than ``--tolerance`` fails the run, as does a
-# vectorized wall slower than the event wall on the same point (use
+# regression gate: any point whose wall time exceeds the committed
+# baseline by more than ``--tolerance`` fails the run (use
 # ``--update-baseline`` for intentional resets, ``--current`` to gate a
-# pre-measured JSON without re-running the grid).
+# pre-measured JSON without re-running the grid).  Wall times are host
+# times of the machine that ran the grid.
 import argparse
 import json
 import sys
@@ -43,17 +41,14 @@ def figures() -> int:
 
 
 # Fixed micro-benchmark grid: (topology, n_gpus, nbytes).  Serial, one
-# simulate pair per point per engine — wall times measure the engine
-# itself, not the sweep pool.  Includes the paper-scale 1 GB point (epoch
-# expansion), tier-shaped two-tier points, and the pod-scale 512/256-GPU
-# points where the O(n^2) flow-materialization cost that motivated the
-# vectorized engine dominates (ROADMAP: fig14-scale sweeps).  The special
+# simulate pair per point — wall times measure the engine itself, not the
+# sweep pool.  Includes the paper-scale 1 GB point (epoch expansion),
+# tier-shaped two-tier points, and the pod-scale 512/256-GPU points where
+# O(n^2) flow materialization would dominate a scalar engine.  The special
 # ("fleet", 16, 0) point times an autoscaled fleet serving run
-# (repro.serving.fleet) on BOTH engines: its tiny decode collectives sat
-# below the vectorization-win size until the serving hot path (geometry
-# memoization + warm fast path, DESIGN.md §15) made the vectorized engine
-# win at serving scale too, so it is now dual-engine and folded into the
-# aggregate speedup like any other point.
+# (repro.serving.fleet): thousands of tiny decode collectives, the regime
+# of the serving hot path (geometry memoization + warm fast path,
+# DESIGN.md §15).
 def _bench_points():
     from repro.core import GB, MB
     return [
@@ -68,58 +63,36 @@ def _bench_points():
     ]
 
 
-def _fleet_bench_point(engine: str):
+def _fleet_bench_point():
     from repro.serving import FleetPoint, TrafficPoint
     traffic = TrafficPoint(
         arch="granite-moe-1b-a400m", rps=16.0, arrival="bursty",
         n_requests=10, seed=7, steps_cap=40, burst_size=4,
-        burstiness=24.0, prompt_mean=64, output_mean=4, engine=engine)
+        burstiness=24.0, prompt_mean=64, output_mean=4)
     return FleetPoint(traffic=traffic, replicas=2, router="least_loaded",
                       autoscale=True, min_replicas=1, max_replicas=2,
                       scale_up_queued=1, scale_down_idle_ns=5e7)
 
 
 def _measure_fleet(n_gpus: int, reps: int, profile: bool = False) -> dict:
-    """Time the fleet serving point on BOTH engines, interleaved best-of.
-
-    Event and vectorized reps alternate so both engines sample the same
-    scheduler-noise environment (shared boxes show 20-30% wall drift
-    between measurement windows; pairing keeps the recorded speedup
-    honest).  The two runs must agree bit-for-bit on every step — the
-    serving stack doubles as a coarse differential check, exactly like
-    the grid points.
-    """
+    """Time the fleet serving point, best-of-``reps``."""
     from repro.serving.fleet import _fleet_point
 
-    walls = {"event": float("inf"), "vectorized": float("inf")}
-    results = {}
+    wall = float("inf")
     for _ in range(reps):
-        for eng in ("event", "vectorized"):
-            t0 = time.perf_counter()
-            results[eng] = _fleet_point((_fleet_bench_point(eng),))
-            walls[eng] = min(walls[eng], time.perf_counter() - t0)
-    res, vec = results["event"], results["vectorized"]
-    key = [(s.t_start, s.t_end, s.comm_ns, s.walks) for s in res.steps]
-    if key != [(s.t_start, s.t_end, s.comm_ns, s.walks)
-               for s in vec.steps]:
-        raise AssertionError(
-            "engine disagreement on the fleet serving point")
+        t0 = time.perf_counter()
+        res = _fleet_point((_fleet_bench_point(),))
+        wall = min(wall, time.perf_counter() - t0)
     if profile:
-        for eng in ("event", "vectorized"):
-            _profile_point(f"fleet/gpus{n_gpus}/serving [{eng}]",
-                           lambda e=eng: _fleet_point(
-                               (_fleet_bench_point(e),)))
+        _profile_point(f"fleet/gpus{n_gpus}/serving",
+                       lambda: _fleet_point((_fleet_bench_point(),)))
     comm = sum(s.comm_ns for s in res.steps)
-    speedup = walls["event"] / walls["vectorized"]
-    print(f"# fleet/gpus{n_gpus}/serving: event {walls['event']:.3f}s, "
-          f"vec {walls['vectorized']:.3f}s ({speedup:.1f}x, "
-          f"{len(res.steps)} steps, {res.spin_ups} spin-ups, "
-          f"fastpath {vec.fastpath_step_fraction:.0%} of steps, "
+    print(f"# fleet/gpus{n_gpus}/serving: {wall:.3f}s "
+          f"({len(res.steps)} steps, {res.spin_ups} spin-ups, "
+          f"fastpath {res.fastpath_step_fraction:.0%} of steps, "
           f"p99_deg={res.p99_ttft_degradation:.4f})", file=sys.stderr)
     return {"topology": "fleet", "n_gpus": n_gpus, "nbytes": 0,
-            "wall_s": round(walls["event"], 4),
-            "wall_vec_s": round(walls["vectorized"], 4),
-            "speedup": round(speedup, 2),
+            "wall_s": round(wall, 4),
             "completion_ns": round(comm, 2),
             "degradation": res.p99_ttft_degradation,
             "requests": len(res.requests)}
@@ -150,14 +123,12 @@ def _profile_point(name: str, fn) -> None:
 
 
 def measure_engine(reps: int = 3, profile: bool = False) -> dict:
-    """Time the fixed grid on both engines; returns the JSON payload.
+    """Time the fixed grid; returns the JSON payload.
 
-    Each point is best-of-``reps`` per engine: the minimum wall time is the
-    least noise-contaminated estimate of the engine's cost, which is what a
+    Each point is best-of-``reps``: the minimum wall time is the least
+    noise-contaminated estimate of the engine's cost, which is what a
     cross-run regression gate must compare (means absorb scheduler noise
-    and flake the gate).  The two engines' results must agree exactly on
-    every point — a mismatch aborts the bench, so a published speedup can
-    never come from a divergent simulation.
+    and flake the gate).
     """
     from repro.core import ratsim
     from repro.core.config import FabricConfig, SimConfig
@@ -170,58 +141,26 @@ def measure_engine(reps: int = 3, profile: bool = False) -> dict:
             continue
         fab = FabricConfig(n_gpus=n, topology=topo, leaf_size=16,
                            oversubscription=2.0, pod_size=16)
-        walls = {}
-        results = {}
-        for eng in ("event", "vectorized"):
-            cfg = SimConfig(fabric=fab, engine=eng)
-            wall = float("inf")
-            for _ in range(reps):
-                t0 = time.perf_counter()
-                c = ratsim.compare(nbytes, n, cfg=cfg)
-                wall = min(wall, time.perf_counter() - t0)
-            walls[eng] = wall
-            results[eng] = c
-            if profile:
-                _profile_point(
-                    f"{topo}/gpus{n}/{nbytes >> 20}MB [{eng}]",
-                    lambda: ratsim.compare(nbytes, n, cfg=cfg))
-        ce = results["event"].baseline
-        cv = results["vectorized"].baseline
-        if (ce.completion_ns != cv.completion_ns
-                or ce.counters.requests != cv.counters.requests
-                or ce.counters.walks != cv.counters.walks
-                or ce.counters.by_class != cv.counters.by_class):
-            raise AssertionError(
-                f"engine disagreement at {topo}/gpus{n}/{nbytes >> 20}MB: "
-                f"event {ce.completion_ns} vs vectorized {cv.completion_ns}")
-        c = results["event"]
-        speedup = walls["event"] / walls["vectorized"]
+        cfg = SimConfig(fabric=fab)
+        wall = float("inf")
+        for _ in range(reps):
+            t0 = time.perf_counter()
+            c = ratsim.compare(nbytes, n, cfg=cfg)
+            wall = min(wall, time.perf_counter() - t0)
+        if profile:
+            _profile_point(f"{topo}/gpus{n}/{nbytes >> 20}MB",
+                           lambda: ratsim.compare(nbytes, n, cfg=cfg))
         points.append({
             "topology": topo, "n_gpus": n, "nbytes": nbytes,
-            "wall_s": round(walls["event"], 4),
-            "wall_vec_s": round(walls["vectorized"], 4),
-            "speedup": round(speedup, 2),
+            "wall_s": round(wall, 4),
             "completion_ns": c.baseline.completion_ns,
             "degradation": c.degradation,
             "requests": c.baseline.counters.requests,
         })
-        print(f"# {topo}/gpus{n}/{nbytes >> 20}MB: "
-              f"event {walls['event']:.3f}s, "
-              f"vec {walls['vectorized']:.3f}s ({speedup:.1f}x, "
-              f"deg={c.degradation:.4f})", file=sys.stderr)
-    # Aggregate speedup over every dual-engine point — since the serving
-    # hot path this includes the fleet serving point, so the headline now
-    # covers scheduler-driven small-collective replay, not just pod-scale
-    # collectives (which is why it is lower than the pre-serving 20x).
-    dual = [p for p in points if "wall_vec_s" in p]
-    tot_e = sum(p["wall_s"] for p in dual)
-    tot_v = sum(p["wall_vec_s"] for p in dual)
-    agg = tot_e / tot_v if tot_v else float("inf")
-    print(f"# aggregate speedup: {tot_e:.3f}s / {tot_v:.3f}s = {agg:.1f}x",
-          file=sys.stderr)
-    return {"grid": "engine-v2",
+        print(f"# {topo}/gpus{n}/{nbytes >> 20}MB: {wall:.3f}s "
+              f"(deg={c.degradation:.4f})", file=sys.stderr)
+    return {"grid": "engine-v3",
             "total_wall_s": round(time.perf_counter() - t_all, 4),
-            "speedup": round(agg, 2),
             "points": points}
 
 
@@ -238,24 +177,16 @@ def _point_name(key: tuple) -> str:
 
 def check_against(current: dict, baseline: dict, tolerance: float,
                   min_delta_s: float = 0.05) -> list:
-    """Per-point wall-time regression gate, both engines.
+    """Per-point wall-time regression gate.
 
     Returns the list of failure messages (empty = gate passes) and prints
     the full delta table either way, so CI logs always show the trajectory.
-    Per grid point it gates
-
-    * the event wall (``wall_s``) and — when both sides carry it — the
-      vectorized wall (``wall_vec_s``) against the committed baseline;
-    * the vectorized wall against the event wall *of the same run*: a
-      vectorized engine slower than the event engine defeats its purpose
-      and fails regardless of what the baseline says.
-
-    ``min_delta_s`` is an absolute floor on every rule: a point only fails
-    when it is both ``tolerance`` slower *and* at least that many seconds
-    slower — millisecond points jitter past any relative tolerance.  A
-    grid mismatch (missing or extra points, e.g. a stale committed
-    baseline after the grid changed) also fails — reset intentionally with
-    ``--update-baseline``.
+    A grid point fails when its wall (``wall_s``) is both ``tolerance``
+    slower than the committed baseline *and* at least ``min_delta_s``
+    seconds slower — millisecond points jitter past any relative
+    tolerance.  A grid mismatch (missing or extra points, e.g. a stale
+    committed baseline after the grid changed) also fails — reset
+    intentionally with ``--update-baseline``.
     """
     base = {_point_key(p): p for p in baseline.get("points", [])}
     cur = {_point_key(p): p for p in current.get("points", [])}
@@ -263,34 +194,21 @@ def check_against(current: dict, baseline: dict, tolerance: float,
     print(f"# bench gate: wall-time tolerance +{tolerance:.0%} per point")
     print(f"{'point':<34s} {'base_s':>8s} {'cur_s':>8s} {'delta':>8s}")
     for key, cp in cur.items():
+        name = _point_name(key)
         bp = base.get(key)
         if bp is None:
-            print(f"{_point_name(key):<34s} {'-':>8s} "
-                  f"{cp['wall_s']:>8.3f} {'new':>8s}")
-            failures.append(f"{_point_name(key)}: not in baseline "
+            print(f"{name:<34s} {'-':>8s} {cp['wall_s']:>8.3f} {'new':>8s}")
+            failures.append(f"{name}: not in baseline "
                             f"(grid changed? --update-baseline)")
             continue
-        for field, tag in (("wall_s", ""), ("wall_vec_s", " [vec]")):
-            if field not in cp or field not in bp:
-                continue
-            name = _point_name(key) + tag
-            delta = (cp[field] - bp[field]) / bp[field] \
-                if bp[field] else float("inf")
-            regressed = (delta > tolerance
-                         and cp[field] - bp[field] > min_delta_s)
-            flag = " REGRESSED" if regressed else ""
-            print(f"{name:<34s} {bp[field]:>8.3f} "
-                  f"{cp[field]:>8.3f} {delta:>+7.1%}{flag}")
-            if regressed:
-                failures.append(
-                    f"{name}: {bp[field]:.3f}s -> "
-                    f"{cp[field]:.3f}s ({delta:+.1%} > +{tolerance:.0%})")
-        if ("wall_vec_s" in cp
-                and cp["wall_vec_s"] > cp["wall_s"]
-                and cp["wall_vec_s"] - cp["wall_s"] > min_delta_s):
-            failures.append(
-                f"{_point_name(key)}: vectorized ({cp['wall_vec_s']:.3f}s) "
-                f"slower than event ({cp['wall_s']:.3f}s)")
+        b, c = bp["wall_s"], cp["wall_s"]
+        delta = (c - b) / b if b else float("inf")
+        regressed = delta > tolerance and c - b > min_delta_s
+        flag = " REGRESSED" if regressed else ""
+        print(f"{name:<34s} {b:>8.3f} {c:>8.3f} {delta:>+7.1%}{flag}")
+        if regressed:
+            failures.append(f"{name}: {b:.3f}s -> {c:.3f}s "
+                            f"({delta:+.1%} > +{tolerance:.0%})")
     for key in base:
         if key not in cur:
             failures.append(f"{_point_name(key)}: in baseline but not "
@@ -303,7 +221,7 @@ def check_against(current: dict, baseline: dict, tolerance: float,
 def main() -> None:
     p = argparse.ArgumentParser(prog="python -m benchmarks.run")
     p.add_argument("--bench-engine", action="store_true",
-                   help="time the fixed engine grid and write a JSON "
+                   help="time the fixed simulator grid and write a JSON "
                         "artifact instead of printing the figure CSV")
     p.add_argument("--out", default="BENCH_engine.json",
                    help="output path for --bench-engine")

@@ -177,12 +177,6 @@ class SimConfig:
                                  # patterns load every GPU identically);
                                  # False simulates every target
     collect_trace: bool = False  # keep per-request latency arrays (figs 9/10)
-    # Simulation engine: "event" (the reference per-epoch Python loop) or
-    # "vectorized" (repro.core.engine_vec — batched numpy arithmetic with a
-    # minimal sequential TLB core; bit-for-bit identical results, ~10x+
-    # faster on sweep-scale points).  Threaded through ratsim, sessions,
-    # workload replay and serving.
-    engine: str = "event"
 
     def replace(self, **kw) -> "SimConfig":
         return dataclasses.replace(self, **kw)

@@ -1,14 +1,13 @@
-"""Vectorized batch engine: the page-epoch model as array arithmetic.
+"""The simulator's engine: the page-epoch model as array arithmetic.
 
-Drop-in replacement for :class:`repro.core.engine.EpochEngine` selected via
-``SimConfig.engine="vectorized"`` (DESIGN.md §12).  The event engine spends
-its time in two places: materializing O(n^2) per-flow Python objects at
-pod scale, and walking a Python loop over every (epoch, station) head of
-large collectives.  This engine removes both:
+Prices each collective step per (flow, page) epoch (DESIGN.md §3, §12).  A
+scalar engine would spend its time in two places: materializing O(n^2)
+per-flow Python objects at pod scale, and walking a Python loop over every
+(epoch, station) head of large collectives.  This engine removes both:
 
 * flow/epoch/head geometry — spacing, arrival times, page spans, station
   striping, ingress totals — is precomputed as numpy arrays
-  (:func:`flows_from_specs` plus the span construction in
+  (:func:`flows_from_specs_multi` plus the span construction in
   :meth:`VecEngine.run_iteration`);
 * only the inherently sequential part remains a Python loop: one
   :meth:`VecTranslationState.access` state-machine call per epoch head (the
@@ -41,13 +40,17 @@ Two serving-scale optimizations sit on top (DESIGN.md §15):
   bit-for-bit by construction.  Engagements are counted on
   ``VecEngine.fastpath_calls`` and surfaced through ``RunResult``.
 
-Bit-for-bit equivalence with the event engine is a hard contract, enforced
-by ``tests/test_engine_diff.py``.  It holds because every float expression
-keeps the event engine's exact operand order (elementwise numpy float64 ops
-are IEEE-identical to scalar Python), accumulations use ``np.cumsum`` (a
-strict left fold, matching the scalar ``+=`` chain — the terms the event
-engine skips contribute exact-zero no-ops), and the optimized LRU below
-reproduces the original's lazy-commit order exactly.
+What the engine is checked against (DESIGN.md §7, §12.2): the
+request-level reference DES (:mod:`repro.core.ref_des`) — exact request,
+walk and probe counts, completion within 5% — and recorded outputs,
+bit for bit: the figure goldens (``tests/test_golden_figs.py``) and the
+output records of ``tests/test_engine_diff.py`` and
+``tests/test_engine_fuzz.py``.  The records stay exact because every float
+expression keeps the operand order of the scalar per-epoch loop they were
+first captured from (elementwise numpy float64 ops are IEEE-identical to
+scalar Python), and accumulations use ``np.cumsum`` (a strict left fold,
+matching a scalar ``+=`` chain — skipped terms contribute exact-zero
+no-ops).
 
 :class:`VecTranslationState` is an operation-for-operation port of
 :class:`repro.core.tlb.TranslationState` with two structural speedups that
@@ -63,10 +66,17 @@ provably preserve the observable sequence of cache operations:
 * ``l1_maybe``/``l2_maybe`` record every page ever fill-staged per cache
   since the last flush.  A page absent from the set cannot be resident, so
   its lookup is a guaranteed miss and is skipped entirely.  Deferring the
-  skipped lookup's lazy commits is safe: commits are totally ordered by
-  ``(fill_time, staging_index)`` and every *taken* lookup first commits all
-  fills up to its own time, so the interleaving of commits, hits
-  (recency updates) and evictions that the caches observe is unchanged.
+  skipped lookup's lazy commits is safe while accesses arrive in time
+  order: commits are totally ordered by ``(fill_time, staging_index)`` and
+  every *taken* lookup first commits all fills up to its own time, so the
+  interleaving of commits, hits (recency updates) and evictions that the
+  caches observe is unchanged.  When they do not — pre-translation probes
+  still issuing after the data stream has begun are replayed ahead of it —
+  deferring keeps a fill invisible until its fill time (DESIGN.md §4),
+  which is what the time-ordered DES does; an eager commit at the late
+  probe's lookup would let an earlier data request hit a translation whose
+  walk has not finished (``tests/test_engine_diff.py::
+  test_late_probe_fill_not_visible_early``).
 """
 from __future__ import annotations
 
@@ -348,8 +358,9 @@ class _Geom:
     """t_start-invariant geometry of one :class:`FlowArrays` (sorted order).
 
     Everything :meth:`VecEngine.run_iteration` derives from the flow set
-    except the absolute arrival times: epoch spans in the event engine's
-    sort order, head geometry, ingress totals, prefetch targets, and the
+    except the absolute arrival times: epoch spans in
+    :func:`~repro.core.ref_des.epoch_spans` sort order, head geometry,
+    ingress totals, prefetch targets, and the
     warm-fast-path structures.  Times are reconstructed per call as
     ``a0[fi] + rel`` with the *same* operands the uncached expression used,
     so reuse is bit-for-bit.
@@ -450,7 +461,7 @@ class FlowArrays:
     """One step's flows at one target as parallel columns.
 
     Row ``i`` carries exactly the fields of the ``i``-th
-    :class:`~repro.core.engine.Flow` that :func:`~repro.core.engine.
+    :class:`~repro.core.ref_des.Flow` that :func:`~repro.core.ref_des.
     flows_for_dst` would build (same order: spec order filtered to this
     target).  ``geom`` is the lazily built t_start-invariant
     :class:`_Geom` cache; sessions reuse ``FlowArrays`` across calls by
@@ -471,66 +482,20 @@ class FlowArrays:
         return len(self.src)
 
 
-def flows_from_specs(step: StepArrays, cfg: SimConfig, dst: int,
-                     t_start: float) -> Optional[FlowArrays]:
-    """Vectorized :func:`repro.core.engine.flows_for_dst`.
-
-    Bandwidth shares count *all* of the step's flows (zero-byte and
-    other-target flows included), matching the event engine; only flows
-    landing at ``dst`` with positive bytes are materialized.  Returns
-    ``None`` for an empty flow set (the event path's ``[]``).
-    """
-    fab = cfg.fabric
-    topo = get_topology(fab)
-    sel = (step.dst == dst) & (step.nbytes > 0)
-    if not sel.any():
-        return None
-    src = step.src[sel]
-    nb = step.nbytes[sel]
-    off = step.offset[sel]
-    rb = fab.request_bytes
-    delta = (rb * step.out_deg()[src]) / fab.gpu_bw
-    if topo.flat:
-        oneway = np.full(len(src), fab.oneway_ns)
-        ret = np.full(len(src), fab.return_ns)
-    else:
-        # Per-(source, tier) degrees are a per-step aggregate over ALL
-        # specs; cached on the StepArrays (steps are built per run, under
-        # one fabric config, so the cache never crosses topologies).
-        if step._tier_cache is None:
-            tier_all = topo.tier_arr(step.src, step.dst)
-            ntier = int(tier_all.max()) + 1 if len(tier_all) else 1
-            step._tier_cache = (ntier,
-                                np.bincount(step.src * ntier + tier_all))
-        ntier, tdeg = step._tier_cache
-        tier_sel = topo.tier_arr(src, dst)
-        for tv in np.unique(tier_sel):
-            cap = topo.tier_capacity(int(tv))
-            if cap is None:
-                continue
-            m = tier_sel == tv
-            shaped = (rb * tdeg[src[m] * ntier + tv]) / cap
-            delta[m] = np.maximum(delta[m], shaped)
-        oneway = topo.path_latency_arr(src, dst)
-        ret = topo.return_latency_arr(dst, src)
-    return FlowArrays(src=src, base_addr=((dst + 1) << 42) + off, nbytes=nb,
-                      t_start=t_start, delta=delta,
-                      stripe=src % fab.stations_per_gpu,
-                      oneway=oneway, ret=ret)
-
-
 def flows_from_specs_multi(step: StepArrays, cfg: SimConfig,
                            dsts: List[int],
                            t_start: float = 0.0) -> Dict[int,
                                                          Optional[FlowArrays]]:
-    """Batched :func:`flows_from_specs` over every simulated target.
+    """Vectorized :func:`repro.core.ref_des.flows_for_dst` over every
+    simulated target.
 
     One vectorized pass — bandwidth shares, tier shaping and per-path
     latencies are computed once over the union of all targets' flows and
     split per destination afterwards (row order within a destination is
-    spec order, exactly as the per-dst build), so the per-target
-    ``FlowArrays`` are element-for-element identical to ``len(dsts)``
-    separate :func:`flows_from_specs` calls at a fraction of the passes.
+    spec order, as in the DES's per-target build).  Bandwidth shares count
+    *all* of the step's flows (zero-byte and other-target flows included);
+    only flows landing at a target with positive bytes are materialized,
+    and a target without any maps to ``None`` (the DES's ``[]``).
     """
     fab = cfg.fabric
     topo = get_topology(fab)
@@ -582,9 +547,9 @@ def rebase_flow_arrays(fa: FlowArrays, delta_addr: int,
     """Clone ``fa`` with ``base_addr`` shifted by ``delta_addr`` bytes.
 
     Integer address adds are exact, so the clone is what
-    :func:`flows_from_specs` would have built at the shifted region.  When
-    the shift is page-aligned the (expensive) cached geometry carries over
-    via :meth:`_Geom.shifted`; otherwise it is rebuilt on first use.
+    :func:`flows_from_specs_multi` would have built at the shifted region.
+    When the shift is page-aligned the (expensive) cached geometry carries
+    over via :meth:`_Geom.shifted`; otherwise it is rebuilt on first use.
     """
     out = FlowArrays(src=fa.src, base_addr=fa.base_addr + delta_addr,
                      nbytes=fa.nbytes, t_start=fa.t_start, delta=fa.delta,
@@ -623,14 +588,13 @@ def run_step_group(engines: dict, grp: List[tuple], t: float,
 
 
 class VecEngine:
-    """Vectorized twin of :class:`repro.core.engine.EpochEngine`.
+    """One simulated target GPU: its translation state and stations.
 
-    Same construction signature and the same surface
-    :class:`~repro.core.session.SimSession` drives (``state``,
-    ``stall_sum``/``stall_n``, ``trace_chunks``, ``run_iteration``), but
-    ``run_iteration`` consumes a :class:`FlowArrays` instead of a
-    ``List[Flow]``.  ``fastpath_calls`` counts ``run_iteration`` calls the
-    warm fast path fully served (DESIGN.md §15.2).
+    :class:`~repro.core.session.SimSession` drives it through ``state``,
+    ``stall_sum``/``stall_n``, ``trace_chunks`` and ``run_iteration``,
+    which prices one step's :class:`FlowArrays`.  ``fastpath_calls`` counts
+    ``run_iteration`` calls the warm fast path fully served
+    (DESIGN.md §15.2).
     """
 
     def __init__(self, cfg: SimConfig, dst: int = 0):
@@ -723,7 +687,7 @@ class VecEngine:
         e_rel = i0 * delta[e_fi]
         t_first = a0[e_fi] + e_rel
         # Tuple sort (t_first, fi, page): (fi, page) pairs are unique, so
-        # the lexsort total order equals the event engine's list.sort().
+        # the lexsort total order equals epoch_spans()'s list.sort().
         order = np.lexsort((page, e_fi, t_first))
         e_fi, page, i0, i1, e_rel = (
             e_fi[order], page[order], i0[order], i1[order], e_rel[order])
@@ -844,9 +808,9 @@ class VecEngine:
                       fi_base: int = 0, first_step: bool = True) -> float:
         """Price one step's flow set; returns absolute completion time.
 
-        Semantics identical to ``EpochEngine.run_iteration``: translation
-        state persists across calls, per-station ingress bookkeeping
-        resets, pre-translation probes fire only on ``first_step``.
+        Translation state persists across calls, per-station ingress
+        bookkeeping resets (DESIGN.md §5.2), pre-translation probes fire
+        only on ``first_step``.
         """
         fab = self._fab
         ns = self._ns
@@ -935,7 +899,7 @@ class VecEngine:
             l1s = self.state.l1
             mut_c = self.state.mut
             if (g.fp_mutc is mut_c and g.fp_epoch == mut_c[0]
-                    and t1_max < g.fp_hmin):
+                    and t1_max < g.fp_hmin and g.fp_chk[0] is l1s):
                 # Epoch skip: no staging and no commit happened anywhere in
                 # this state since the last full check, so every L1's
                 # resident set and heap are exactly as observed then — the
@@ -943,6 +907,10 @@ class VecEngine:
                 # earliest staged commit still lies beyond this window.
                 # Recency moves don't bump the epoch; they can't change
                 # either fact.  Verdict carries over without the loops.
+                # The rows must still be this state's: sessions sharing a
+                # process-cached plan share ``g``, and another state's
+                # declined check rebuilds ``fp_chk`` without touching
+                # ``fp_mutc``.
                 rows = g.fp_chk[1]
                 ok = True
             else:
@@ -1236,7 +1204,7 @@ class VecEngine:
         # when read back).  Only those first heads — plus every head at a
         # non-quiet station or of a stale-pending group — run the
         # sequential machinery, in head order, preserving the exact
-        # L2/PTW/commit interleaving the event engine sees.
+        # L2/PTW/commit interleaving the per-head loop sees.
         if g.no_bp and not pf_cols and H and not collect_trace:
             l2_lat = state._l2_lat
             # Per-station lookup windows.  The quiet argument is local to
@@ -1415,8 +1383,7 @@ class VecEngine:
                     fill_app(fill)
                     t0_app(t0)
                     kc[kls] += 1
-                    # Ingress-buffer backpressure (same predicate
-                    # expressions as the event engine, term for term).
+                    # Ingress-buffer backpressure (DESIGN.md §3).
                     if (resolve - (t0 + l1_lat) > 0
                             and totals_l[s] - consumed[s] >= ingress):
                         block_from = t0 + cover
@@ -1629,7 +1596,7 @@ class VecEngine:
 
         # rat_ns_sum: strict left fold over [rat0, hum, hits] per head, in
         # head order, seeded with the running value — cumsum is sequential,
-        # and the zero terms the event engine skips are exact no-ops.
+        # and the zero terms of tail-less heads are exact no-ops.
         contrib = np.empty(3 * H + 1)
         contrib[0] = ctr.rat_ns_sum
         contrib[1::3] = rat0
@@ -1665,7 +1632,7 @@ class VecEngine:
     # -- tracing -------------------------------------------------------------
     def _write_trace(self, fi_base, e_fi, i0, i1, hcum, h_is0, h_ns, t0,
                      rat0, fill, h_stride, ns, l1_lat, res=None) -> None:
-        """Per-epoch trace rows, same expressions as the event engine."""
+        """Per-epoch trace rows, same expressions as the tail expansion."""
         for e in range(len(e_fi)):
             tr = np.empty(int(i1[e] - i0[e]))
             for h in range(int(hcum[e]), int(hcum[e + 1])):

@@ -58,10 +58,10 @@ class FlowSpec:
 
 @dataclass
 class StepArrays:
-    """One collective step as parallel columns (vectorized engine form).
+    """One collective step as parallel columns (the engine's form).
 
     Row ``i`` is exactly ``steps()[k][i]`` — same flows, same order — so the
-    event engine and the vectorized engine consume the *same* schedule, just
+    engine and the reference DES consume the *same* schedule, just
     materialized as arrays instead of per-flow objects.  ``out_deg``/
     ``tier_deg`` are lazily cached per-step aggregates (they depend only on
     the step, not on the simulated target).
@@ -89,8 +89,9 @@ class StepArrays:
                           nbytes=self.nbytes, offset=self.offset)
 
     def out_deg(self) -> np.ndarray:
-        """Per-source concurrent-flow count of this step (ALL flows — the
-        event engine counts zero-byte flows toward the bandwidth split)."""
+        """Per-source concurrent-flow count of this step (ALL flows —
+        zero-byte flows count toward the bandwidth split, as in
+        :func:`repro.core.ref_des.flows_for_dst`)."""
         if self._out_deg is None:
             self._out_deg = np.bincount(
                 self.src, minlength=int(self.src.max()) + 1 if len(self.src)
@@ -214,7 +215,7 @@ class AllToAll(CollectivePattern):
     def steps_arrays(self, nbytes, fab):
         # Native array construction preserving steps()'s dst-major order
         # (``for dst ... for src ... if src != dst``) — the O(n^2) listcomp
-        # dominates pod-scale sweep points, so the vectorized engine never
+        # dominates pod-scale sweep points, so the engine never
         # pays it.
         n = fab.n_gpus
         chunk = nbytes // n
@@ -514,8 +515,9 @@ def simulated_dsts(pattern: CollectivePattern, step_specs, symmetric: bool,
                    fab: FabricConfig) -> List[int]:
     """Target GPUs a simulator must model for this pattern.
 
-    Shared by the epoch engine and the reference DES — oracle-equivalence
-    tests only bind if both sides simulate the same target set.
+    The reference DES's form of :func:`simulated_dsts_arrays` —
+    oracle-equivalence tests only bind if both sides simulate the same
+    target set.
     """
     if symmetric and pattern.symmetric:
         return [pattern.representative_dst(fab)]

@@ -33,7 +33,7 @@ from .config import SimConfig, FabricConfig, paper_config, MB
 from .engine import simulate, RunResult
 from .patterns import LOGICAL, PATTERNS
 from .select import get_policy
-from .session import ENGINES, SimSession
+from .session import SimSession
 from .topology import TOPOLOGIES
 
 
@@ -59,7 +59,6 @@ class Comparison:
 def _resolve_cfg(n_gpus: int, collective: Optional[str],
                  cfg: Optional[SimConfig], cfg_kw,
                  topology: Optional[str] = None,
-                 engine: Optional[str] = None,
                  policy=None, nbytes: Optional[int] = None) -> SimConfig:
     cfg = cfg or paper_config(n_gpus, **cfg_kw)
     if collective is not None:
@@ -67,8 +66,6 @@ def _resolve_cfg(n_gpus: int, collective: Optional[str],
     if topology is not None:
         cfg = cfg.replace(
             fabric=dataclasses.replace(cfg.fabric, topology=topology))
-    if engine is not None:
-        cfg = cfg.replace(engine=engine)
     if policy is not None:
         # Free-standing runs start against stone-cold TLBs: the policy
         # resolves a logical collective for the cold state (sessions track
@@ -81,27 +78,25 @@ def _resolve_cfg(n_gpus: int, collective: Optional[str],
 
 
 def run(nbytes: int, n_gpus: int = 16, *, collective: Optional[str] = None,
-        topology: Optional[str] = None, engine: Optional[str] = None,
-        policy=None, cfg: Optional[SimConfig] = None, **cfg_kw) -> RunResult:
+        topology: Optional[str] = None, policy=None,
+        cfg: Optional[SimConfig] = None, **cfg_kw) -> RunResult:
     return simulate(nbytes, _resolve_cfg(n_gpus, collective, cfg, cfg_kw,
-                                         topology, engine, policy, nbytes))
+                                         topology, policy, nbytes))
 
 
 def compare(nbytes: int, n_gpus: int = 16, *,
             collective: Optional[str] = None,
-            topology: Optional[str] = None, engine: Optional[str] = None,
-            policy=None, cfg: Optional[SimConfig] = None,
-            **cfg_kw) -> Comparison:
-    cfg = _resolve_cfg(n_gpus, collective, cfg, cfg_kw, topology, engine,
-                       policy, nbytes)
+            topology: Optional[str] = None, policy=None,
+            cfg: Optional[SimConfig] = None, **cfg_kw) -> Comparison:
+    cfg = _resolve_cfg(n_gpus, collective, cfg, cfg_kw, topology, policy,
+                       nbytes)
     return Comparison(baseline=simulate(nbytes, cfg),
                       ideal=simulate(nbytes, cfg.ideal()))
 
 
 def session(n_gpus: int = 16, *, collective: Optional[str] = None,
-            topology: Optional[str] = None, engine: Optional[str] = None,
-            policy=None, cfg: Optional[SimConfig] = None,
-            **cfg_kw) -> SimSession:
+            topology: Optional[str] = None, policy=None,
+            cfg: Optional[SimConfig] = None, **cfg_kw) -> SimSession:
     """A persistent-TLB session on a fresh pod (repro.core.session).
 
     ``policy`` is attached to the session (per-run cold/warm resolution),
@@ -109,7 +104,7 @@ def session(n_gpus: int = 16, *, collective: Optional[str] = None,
     the warmth its target region actually has at that point.
     """
     return SimSession(_resolve_cfg(n_gpus, collective, cfg, cfg_kw,
-                                   topology, engine), policy=policy)
+                                   topology), policy=policy)
 
 
 # ---------------------------------------------------------------- sweeps
@@ -150,16 +145,14 @@ def _spawnable() -> bool:
     return bool(path) and os.path.exists(path)
 
 
-def _validate_sweep_axes(colls, topos, engine, policy) -> None:
+def _validate_sweep_axes(colls, topos, policy) -> None:
     """Fail fast on bad axis names, before any pool dispatch.
 
-    A typo'd collective/engine/topology used to surface as a worker
+    A typo'd collective/topology used to surface as a worker
     traceback deep inside the process pool; every name is checked here
     against its registry so the error happens eagerly in the caller, with
     the registry contents in the message.
     """
-    if engine is not None and engine not in ENGINES:
-        raise ValueError(f"unknown engine {engine!r}; known: {ENGINES}")
     for topo in topos:
         if topo is not None and topo not in TOPOLOGIES:
             raise ValueError(f"unknown topology {topo!r}; known: "
@@ -183,7 +176,6 @@ def _validate_sweep_axes(colls, topos, engine, policy) -> None:
 def sweep(sizes, gpu_counts, *, collectives: Optional[Iterable[str]] = None,
           topologies: Optional[Iterable[str]] = None,
           base_cfg: Optional[SimConfig] = None,
-          engine: Optional[str] = None,
           policy=None,
           workers: Optional[int] = None,
           cache: Optional[MutableMapping] = None,
@@ -197,11 +189,8 @@ def sweep(sizes, gpu_counts, *, collectives: Optional[Iterable[str]] = None,
     with both, keys are ``(topology, collective, n_gpus, size)``.  Tier
     parameters (leaf size, oversubscription, pod size) come from
     ``base_cfg``'s fabric when given, else the ``FabricConfig`` defaults.
-    ``engine`` overrides ``SimConfig.engine`` on every point (bit-for-bit
-    identical numbers; ``"vectorized"`` prices large grids ~10x faster —
-    note the two engines memoize under distinct cache keys).  ``policy``
-    (see :func:`repro.core.select.get_policy`) resolves each point's
-    collective — which may then be a *logical* class name like
+    ``policy`` (see :func:`repro.core.select.get_policy`) resolves each
+    point's collective — which may then be a *logical* class name like
     ``"allreduce"`` — to a concrete algorithm before dispatch; axis names
     are validated eagerly either way, so typos fail here rather than as a
     worker traceback.
@@ -227,7 +216,7 @@ def sweep(sizes, gpu_counts, *, collectives: Optional[Iterable[str]] = None,
     seen_inflight: Dict[tuple, tuple] = {}
     colls = list(collectives) if collectives is not None else [None]
     topos = list(topologies) if topologies is not None else [None]
-    _validate_sweep_axes(colls, topos, engine, policy)
+    _validate_sweep_axes(colls, topos, policy)
     pol = get_policy(policy)
     for topo in topos:
         for coll in colls:
@@ -246,8 +235,6 @@ def sweep(sizes, gpu_counts, *, collectives: Optional[Iterable[str]] = None,
                     if topo is not None:
                         cfg = cfg.replace(fabric=dataclasses.replace(
                             cfg.fabric, topology=topo))
-                    if engine is not None:
-                        cfg = cfg.replace(engine=engine)
                     if pol is not None:
                         # Per-point resolution (cold state: each sweep
                         # point is a free-standing run on a fresh pod).

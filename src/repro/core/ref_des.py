@@ -1,16 +1,16 @@
 """Request-level reference DES for the RAT simulator (the oracle).
 
-Simulates every individual request through the same
-:class:`~repro.core.tlb.TranslationState` machinery as the page-epoch engine,
-but with explicit per-station in-order FIFOs and slot-accurate ingress
-buffering instead of closed-form epoch expansion.  Replays exactly the flow
-sets the pattern layer (:mod:`repro.core.patterns`) emits — one station-queue
-episode per collective step, barriered on the previous step's completion —
-and, when the latency-hiding optimizations are enabled, issues the *same*
-pre-translation / prefetch probe schedule the engine issues (built from the
-shared :func:`~repro.core.engine.epoch_spans` /
-:func:`~repro.core.engine.probe_station` helpers), so oracle-equivalence
-tests bind for the optimization paths too.
+Simulates every individual request through the
+:class:`~repro.core.tlb.TranslationState` machinery, with explicit
+per-station in-order FIFOs and slot-accurate ingress buffering instead of
+the engine's closed-form epoch expansion (:mod:`repro.core.engine_vec`).
+Replays exactly the flow sets the pattern layer (:mod:`repro.core.patterns`)
+emits — one station-queue episode per collective step, barriered on the
+previous step's completion — and, when the latency-hiding optimizations are
+enabled, issues the *same* pre-translation / prefetch probe schedule the
+engine issues (:func:`pretranslate_probes`, :func:`epoch_spans`,
+:func:`probe_station`), so oracle-equivalence tests bind for the
+optimization paths too.
 
 :class:`RefSession` mirrors :class:`repro.core.session.SimSession` — a
 persistent-TLB session replaying a sequence of collectives — and
@@ -21,16 +21,161 @@ from __future__ import annotations
 
 import heapq
 import math
+from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
 from .config import SimConfig
-from .engine import (Flow, IterationResult, RunResult, epoch_spans,
-                     flows_for_dst, pretranslate_probes, probe_station)
+from .engine import IterationResult, RunResult
+from .patterns import FlowSpec
 from .select import get_policy, session_collective
 from .session import CollectiveResult, resolve_collective
 from .tlb import Counters, TranslationState
+from .topology import get_topology
+
+
+@dataclass
+class Flow:
+    """One (source -> target) stream of the all-pairs schedule."""
+
+    src: int
+    dst: int
+    base_addr: int      # NPA of the region this flow writes at the target
+    nbytes: int
+    t_start: float      # issue time of request 0 at the source CU
+    delta_ns: float     # request inter-issue spacing (per-flow BW share)
+    stripe: int         # station offset for round-robin striping
+    oneway_ns: float = 0.0  # source CU -> target station (topology path)
+    return_ns: float = 0.0  # target -> source ack (topology path)
+
+
+def flows_for_dst(specs: List[FlowSpec], cfg: SimConfig, dst: int,
+                  t_start: float) -> List[Flow]:
+    """Materialize one step's :class:`FlowSpec` set as flows at ``dst``.
+
+    Per-flow bandwidth share: a source's concurrent outgoing flows of the
+    step split its station pool evenly, so the inter-request spacing is
+    ``request_bytes * out_degree / gpu_bw`` (the all-pairs ``n - 1`` case of
+    the seed engine generalized to arbitrary step out-degrees).  On
+    hierarchical topologies a flow crossing a capacity-limited tier is
+    additionally paced by its share of *that tier's* per-source capacity —
+    a source's flows crossing an oversubscribed uplink split the uplink,
+    not the flat station pool — and each flow carries the topology's
+    per-path request/ack latencies (DESIGN.md §10).
+    """
+    fab = cfg.fabric
+    topo = get_topology(fab)
+    flat = topo.flat
+    out_deg: Dict[int, int] = {}
+    tier_deg: Dict[Tuple[int, int], int] = {}
+    for s in specs:
+        out_deg[s.src] = out_deg.get(s.src, 0) + 1
+        if not flat:
+            k = (s.src, topo.tier(s.src, s.dst))
+            tier_deg[k] = tier_deg.get(k, 0) + 1
+    dst_base = (dst + 1) << 42  # distinct 4 TB NPA region per target GPU
+    oneway = fab.oneway_ns
+    ret = fab.return_ns
+    flows = []
+    for s in specs:
+        if s.dst != dst or s.nbytes <= 0:
+            continue
+        delta = fab.request_bytes * out_deg[s.src] / fab.gpu_bw
+        if not flat:
+            tier = topo.tier(s.src, dst)
+            cap = topo.tier_capacity(tier)
+            if cap is not None:
+                shaped = fab.request_bytes * tier_deg[(s.src, tier)] / cap
+                if shaped > delta:
+                    delta = shaped
+            oneway = topo.path_latency_ns(s.src, dst)
+            ret = topo.return_latency_ns(dst, s.src)
+        flows.append(Flow(
+            src=s.src, dst=dst,
+            base_addr=dst_base + s.offset,
+            nbytes=s.nbytes,
+            t_start=t_start,
+            delta_ns=delta,
+            stripe=s.src % fab.stations_per_gpu,
+            oneway_ns=oneway,
+            return_ns=ret,
+        ))
+    return flows
+
+
+def epoch_spans(flows: List[Flow], rb: int, page_bytes: int):
+    """(first_arrival, flow_idx, page, i0, i1) spans, sorted by arrival.
+
+    One span per (flow, page): requests ``i0..i1-1`` of flow ``flow_idx``
+    touch ``page``.  Arrivals use each flow's own topology path latency
+    (``Flow.oneway_ns``).  The DES's prefetch probes fire at each span's
+    first arrival; the engine builds the same spans as arrays
+    (:meth:`repro.core.engine_vec.VecEngine._build_geom`).
+    """
+    eps = []
+    for fi, f in enumerate(flows):
+        n_req = max(1, math.ceil(f.nbytes / rb))
+        a0 = f.t_start + f.oneway_ns
+        first_page = f.base_addr // page_bytes
+        last_page = (f.base_addr + f.nbytes - 1) // page_bytes
+        for page in range(first_page, last_page + 1):
+            lo = max(f.base_addr, page * page_bytes)
+            hi = min(f.base_addr + f.nbytes, (page + 1) * page_bytes)
+            i0 = (lo - f.base_addr) // rb
+            i1 = min(n_req, math.ceil((hi - f.base_addr) / rb))
+            if i1 <= i0:
+                continue
+            eps.append((a0 + i0 * f.delta_ns, fi, page, i0, i1))
+    eps.sort()
+    return eps
+
+
+def probe_station(f: Flow, page: int, page_bytes: int, rb: int,
+                  ns: int) -> int:
+    """Station where ``page``'s first real request of flow ``f`` lands.
+
+    Request ``i`` of a flow stripes to station ``(i + f.stripe) % ns``; the
+    first request touching ``page`` has index ``i0 = (lo - base) // rb``
+    (``lo`` = first byte of the page inside the flow's range).  Translation
+    probes must target exactly this station so they warm the L1 that the
+    page's first data request will actually query.
+    """
+    lo = max(f.base_addr, page * page_bytes)
+    i0 = (lo - f.base_addr) // rb
+    return (i0 + f.stripe) % ns
+
+
+def pretranslate_probes(flows: List[Flow], cfg: SimConfig):
+    """Yield (t, station, page) pre-translation probes for one collective.
+
+    Paper §6.1: probes issue during the preceding compute window, starting
+    ``lead_time_ns`` before the collective, paced every
+    ``probe_issue_interval_ns``, warming the first ``pages_per_flow`` pages
+    of every flow (0 => all).  The engine issues the same (t, station,
+    page) stream from arrays (:meth:`repro.core.engine_vec.VecEngine.
+    _pretranslate`).
+    """
+    pre = cfg.pretranslation
+    fab = cfg.fabric
+    ns = fab.stations_per_gpu
+    rb = fab.request_bytes
+    page_bytes = cfg.translation.page_bytes
+    if not flows:
+        return
+    t = flows[0].t_start - pre.lead_time_ns
+    k = 0
+    for f in flows:
+        first_page = f.base_addr // page_bytes
+        last_page = (f.base_addr + f.nbytes - 1) // page_bytes
+        n_pages = last_page - first_page + 1
+        limit = n_pages if pre.pages_per_flow <= 0 else min(
+            n_pages, pre.pages_per_flow)
+        for j in range(limit):
+            page = first_page + j
+            yield (t + k * pre.probe_issue_interval_ns,
+                   probe_station(f, page, page_bytes, rb, ns), page)
+            k += 1
 
 
 class _StationQueue:

@@ -22,9 +22,9 @@ Three policies:
   (ring allreduce, direct all-to-all, ...), state-independent.  This is the
   default everywhere, reproducing the pre-policy traces bit-for-bit.
 * :class:`AutoPolicy` — exhaustive simulate-and-pick: prices every feasible
-  candidate with the vectorized engine (two back-to-back iterations: the
-  first is the cold completion, the second the warm one) and picks the
-  minimum for the requested state.  Memoized per (candidate, size, fabric).
+  candidate (two back-to-back iterations: the first is the cold
+  completion, the second the warm one) and picks the minimum for the
+  requested state.  Memoized per (candidate, size, fabric).
 * :class:`PolicyTable` — a cached resolution table keyed by
   ``(logical, size bucket, topology, n_gpus, state)``, JSON-serializable
   (:meth:`PolicyTable.save`) and loadable without importing jax or pricing
@@ -144,13 +144,11 @@ class AutoPolicy(AlgorithmPolicy):
 
     name = "auto"
 
-    def __init__(self, engine: str = "vectorized",
-                 base: Optional[SimConfig] = None):
+    def __init__(self, base: Optional[SimConfig] = None):
         # ``base`` is the deployment config candidates are priced under
         # (page size, TLB geometry, pre-translation/prefetch, ...); its
-        # fabric/collective/engine/iterations fields are overridden per
+        # fabric/collective/iterations fields are overridden per
         # candidate.  None prices under the Table-1 defaults.
-        self.engine = engine
         self.base = base
         self._scores: Dict[tuple, Dict[str, Tuple[float, float]]] = {}
 
@@ -165,8 +163,7 @@ class AutoPolicy(AlgorithmPolicy):
         out: Dict[str, Tuple[float, float]] = {}
         base = self.base if self.base is not None else SimConfig()
         for cand in candidates_for(logical, fab):
-            cfg = base.replace(fabric=fab, collective=cand,
-                               engine=self.engine, iterations=2,
+            cfg = base.replace(fabric=fab, collective=cand, iterations=2,
                                symmetric=True, collect_trace=False)
             res = simulate(nbytes, cfg)
             out[cand] = (res.iterations[0].completion_ns,
@@ -267,7 +264,7 @@ def build_policy_table(
         logicals=("all_to_all", "allreduce", "all_gather", "reduce_scatter"),
         topologies=("single_clos",),
         leaf_size: int = 0, oversubscription: float = 1.0, pod_size: int = 0,
-        engine: str = "vectorized", base: Optional[SimConfig] = None,
+        base: Optional[SimConfig] = None,
         auto: Optional[AutoPolicy] = None) -> PolicyTable:
     """Exhaustively price the grid and cache the per-state optima.
 
@@ -277,7 +274,7 @@ def build_policy_table(
     of two — to avoid the ambiguity).  The builder reuses one
     :class:`AutoPolicy` so candidate completions are priced exactly once.
     """
-    auto = auto or AutoPolicy(engine=engine, base=base)
+    auto = auto or AutoPolicy(base=base)
     entries: Dict[tuple, str] = {}
     table = PolicyTable()
     for topo in topologies:
@@ -293,7 +290,7 @@ def build_policy_table(
                         res = auto.resolve(logical, nbytes, fab, state)
                         entries[table.key(logical, nbytes, fab,
                                           state)] = res.collective
-    meta = dict(engine=engine, sizes=[int(s) for s in sizes],
+    meta = dict(sizes=[int(s) for s in sizes],
                 gpu_counts=[int(n) for n in gpu_counts],
                 topologies=list(topologies), logicals=list(logicals))
     if auto.base is not None:
@@ -322,7 +319,7 @@ def session_collective(policy: Optional[AlgorithmPolicy], cfg: SimConfig,
                        n_gpus: Optional[int], warm: bool) -> Optional[str]:
     """Per-call policy resolution shared by SimSession and RefSession.
 
-    One helper so the engine session and the oracle mirror resolve
+    One helper so the session and the oracle mirror resolve
     identically (the oracle-equivalence contract extends to policies).
     ``warm`` is the caller's view of the target region's TLB state.
     Returns the concrete name to run (or the untouched ``collective`` when
@@ -362,8 +359,6 @@ def main(argv=None) -> int:
     p.add_argument("--logicals",
                    default="all_to_all,allreduce,all_gather,reduce_scatter",
                    help=f"comma list of logical classes {sorted(LOGICAL)}")
-    p.add_argument("--engine", default="vectorized",
-                   choices=("event", "vectorized"))
     p.add_argument("--page-kb", type=int, default=0,
                    help="translation page size in KB candidates are priced "
                         "under (0: Table-1 default, 2 MB).  Small pages are "
@@ -386,8 +381,7 @@ def main(argv=None) -> int:
         base = SimConfig(translation=TranslationConfig(
             page_bytes=args.page_kb * KB))
     table = build_policy_table(sizes, gpus, logicals=logicals,
-                               topologies=topos, engine=args.engine,
-                               base=base)
+                               topologies=topos, base=base)
     table.save(args.out)
     fixed = FixedPolicy()
     diverging = sum(

@@ -4,12 +4,12 @@ The paper's headline result — cold Link-TLB misses cost up to 1.4x on small
 collectives while warmed caches erase the overhead — is a statement about
 *sequences* of collectives: an inference decode loop fires one small MoE
 all-to-all per layer per token, and only the very first invocations pay the
-cold-walk tax.  :class:`SimSession` holds one :class:`~repro.core.engine.
-EpochEngine` per simulated target GPU (and hence one
-:class:`~repro.core.tlb.TranslationState`) across successive collective
-invocations, so TLB/PWC warmth carries from call to call exactly as it would
-on hardware.  :func:`repro.core.engine.simulate` is a thin wrapper: one
-session, ``cfg.iterations`` back-to-back runs.
+cold-walk tax.  :class:`SimSession` holds one :class:`~repro.core.
+engine_vec.VecEngine` per simulated target GPU (and hence one translation
+state) across successive collective invocations, so TLB/PWC warmth carries
+from call to call exactly as it would on hardware.
+:func:`repro.core.engine.simulate` is a thin wrapper: one session,
+``cfg.iterations`` back-to-back runs.
 
 Sessions support:
 
@@ -30,15 +30,13 @@ The request-level oracle mirror is :class:`repro.core.ref_des.RefSession`.
 from __future__ import annotations
 
 import dataclasses
-import math
 from dataclasses import dataclass
 from typing import Dict, List, Optional
 
 import numpy as np
 
 from .config import SimConfig
-from .engine import (EpochEngine, IterationResult, RunResult,
-                     flows_for_dst)
+from .engine import IterationResult, RunResult
 from .engine_vec import (VecEngine, flows_from_specs_multi,
                          rebase_flow_arrays, request_counts,
                          run_step_group)
@@ -46,8 +44,6 @@ from .patterns import (get_pattern, simulated_dsts, simulated_dsts_arrays)
 from .select import get_policy, session_collective
 from .tlb import Counters
 from .topology import get_topology
-
-ENGINES = ("event", "vectorized")
 
 
 def _group_fabric(cfg: SimConfig, collective: Optional[str],
@@ -95,10 +91,10 @@ def resolve_collective(cfg: SimConfig, nbytes: int,
                        rank_stride: int = 1):
     """(name, fab_n, step_specs, dsts) for one session run.
 
-    Single source of truth for per-call pattern/group resolution and
-    validation, shared by :class:`SimSession` and
-    :class:`~repro.core.ref_des.RefSession` so the two sides of the
-    oracle-equivalence contract cannot drift.
+    The oracle's (:class:`~repro.core.ref_des.RefSession`) per-call
+    pattern/group resolution; :class:`SimSession` resolves through
+    :func:`resolve_collective_arrays`, which shares every check with it, so
+    the two sides of the oracle-equivalence contract cannot drift.
 
     ``rank_stride`` places the group's logical ranks onto pod GPUs
     ``0, stride, 2*stride, ...`` instead of ``0..g-1`` — a data-parallel
@@ -124,7 +120,7 @@ def resolve_collective_arrays(cfg: SimConfig, nbytes: int,
                               collective: Optional[str],
                               n_gpus: Optional[int], rank_stride: int = 1):
     """:func:`resolve_collective` in the columnar :class:`~repro.core.
-    patterns.StepArrays` form consumed by the vectorized engine.
+    patterns.StepArrays` form consumed by the engine.
 
     Same validation, same stride placement, same symmetric demotion and the
     same target set — only the schedule representation differs.
@@ -150,8 +146,8 @@ class CollectiveResult:
     t_start: float        # absolute session time the collective was issued
     t_end: float          # absolute completion time
     counters: Counters    # counter deltas attributable to this invocation
-    # run_iteration calls fully served by the vectorized warm fast path
-    # (DESIGN.md §15.2); always 0 on the event engine.
+    # run_iteration calls fully served by the warm fast path
+    # (DESIGN.md §15.2).
     fastpath_calls: int = 0
 
     @property
@@ -174,8 +170,9 @@ class _Plan:
     steps: List[List[tuple]]
     trace_dst: Optional[int]
     base_offset: int
-    # Target construction order (the event path's per-call order); sessions
-    # adopting a process-cached plan instantiate engines from this.
+    # Simulated targets in resolution order; sessions adopting a
+    # process-cached plan instantiate engines from this, so counters
+    # always merge in the same order.
     dsts: tuple = ()
 
 
@@ -209,18 +206,14 @@ class SimSession:
     """
 
     def __init__(self, cfg: SimConfig, *, compute_profile=None, policy=None):
-        if cfg.engine not in ENGINES:
-            raise ValueError(
-                f"unknown engine {cfg.engine!r}; known: {ENGINES}")
         self.cfg = cfg
         self.compute_profile = compute_profile
         self.policy = get_policy(policy)
         self._warm_regions: set = set()   # base_offsets touched since flush
-        self._vec = cfg.engine == "vectorized"
         self.t = 0.0
         self.records: List[CollectiveResult] = []
-        self._engines: Dict[int, EpochEngine] = {}
-        # Geometry plan cache (vectorized engine only, DESIGN.md §15.1):
+        self._engines: Dict[int, VecEngine] = {}
+        # Geometry plan cache (DESIGN.md §15.1):
         # _plans is keyed on the full call signature; _canonical holds one
         # representative per offset-free signature that other offsets are
         # derived from by (exact) integer address translation.  Entries are
@@ -284,11 +277,10 @@ class SimSession:
             self._warm_regions.clear()
 
     # -- engines -------------------------------------------------------------
-    def _engine(self, dst: int) -> EpochEngine:
+    def _engine(self, dst: int) -> VecEngine:
         eng = self._engines.get(dst)
         if eng is None:
-            cls = VecEngine if self._vec else EpochEngine
-            eng = self._engines[dst] = cls(self.cfg, dst=dst)
+            eng = self._engines[dst] = VecEngine(self.cfg, dst=dst)
         return eng
 
     def _counters_total(self) -> Counters:
@@ -298,10 +290,9 @@ class SimSession:
         return total
 
     def _fastpath_total(self) -> int:
-        return sum(getattr(eng, "fastpath_calls", 0)
-                   for eng in self._engines.values())
+        return sum(eng.fastpath_calls for eng in self._engines.values())
 
-    # -- geometry plans (vectorized engine, DESIGN.md §15.1) -----------------
+    # -- geometry plans (DESIGN.md §15.1) ------------------------------------
     def _plan_for(self, collective: Optional[str], nbytes: int,
                   n_gpus: Optional[int], rank_stride: int,
                   base_offset: int) -> _Plan:
@@ -323,8 +314,7 @@ class SimSession:
             plan = _PLAN_CACHE.get(gkey)
             if plan is not None:
                 # Engine (and TLB state) per simulated target exists up
-                # front, matching the event path's per-call construction
-                # order.
+                # front, in the plan's target order.
                 for d in plan.dsts:
                     self._engine(d)
                 self._plans[key] = plan
@@ -415,59 +405,29 @@ class SimSession:
         rb = fab.request_bytes
         t0 = self.t
         t = t0
-        if self._vec:
-            plan = self._plan_for(collective, nbytes, n_gpus, rank_stride,
-                                  base_offset)
-            name, fab_n = plan.name, plan.fab_n
-            if collect:
-                self._trace_dst = plan.trace_dst
-            engines = self._engines
-            if collect:
-                for si, grp in enumerate(plan.steps):
-                    comp = t
-                    first = si == 0
-                    for d, fa in grp:
-                        fa.t_start = t
-                        trace_this = d == self._trace_dst
-                        fi_base = len(self._flow_sizes)
-                        if trace_this:
-                            self._flow_sizes.extend(request_counts(fa, rb))
-                        comp = max(comp, engines[d].run_iteration(
-                            fa, trace_this, fi_base=fi_base,
-                            first_step=first))
-                    t = comp
-            else:
-                # Hot path: one grouped invocation per step barrier
-                # (DESIGN.md §15).
-                for si, grp in enumerate(plan.steps):
-                    t = run_step_group(engines, grp, t, si == 0)
-        else:
-            name, fab_n, step_specs, dsts = resolve_collective(
-                cfg, nbytes, collective, n_gpus, rank_stride)
-            if collect:
-                self._trace_dst = next(
-                    (d for d in dsts
-                     if any(s.dst == d and s.nbytes > 0
-                            for step in step_specs for s in step)), None)
-            for si, specs in enumerate(step_specs):
+        plan = self._plan_for(collective, nbytes, n_gpus, rank_stride,
+                              base_offset)
+        name, fab_n = plan.name, plan.fab_n
+        engines = self._engines
+        if collect:
+            self._trace_dst = plan.trace_dst
+            for si, grp in enumerate(plan.steps):
                 comp = t
-                for d in dsts:
-                    eng = self._engine(d)
-                    flows = flows_for_dst(specs, cfg, d, t_start=t)
-                    if base_offset:
-                        for f in flows:
-                            f.base_addr += base_offset
-                    if not flows:
-                        continue
-                    trace_this = collect and d == self._trace_dst
+                first = si == 0
+                for d, fa in grp:
+                    fa.t_start = t
+                    trace_this = d == self._trace_dst
                     fi_base = len(self._flow_sizes)
                     if trace_this:
-                        self._flow_sizes.extend(
-                            max(1, math.ceil(f.nbytes / rb)) for f in flows)
-                    comp = max(comp, eng.run_iteration(
-                        flows, trace_this, fi_base=fi_base,
-                        first_step=si == 0))
+                        self._flow_sizes.extend(request_counts(fa, rb))
+                    comp = max(comp, engines[d].run_iteration(
+                        fa, trace_this, fi_base=fi_base, first_step=first))
                 t = comp
+        else:
+            # Hot path: one grouped invocation per step barrier
+            # (DESIGN.md §15).
+            for si, grp in enumerate(plan.steps):
+                t = run_step_group(engines, grp, t, si == 0)
         self.t = t
 
         after = self._counters_total()

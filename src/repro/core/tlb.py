@@ -6,10 +6,12 @@ page-walk caches -> shared pool of parallel page-table walkers.  Fill policy
 is mostly-inclusive: a completed walk populates both the L2 and the
 requesting station's L1; L2 evictions do not back-invalidate L1s.
 
-The model is event-free: callers (the page-epoch engine and the request-level
-reference DES) invoke :meth:`TranslationState.access` in non-decreasing time
-order and the state machine returns the translation-resolve time plus the
-classification used for the paper's Fig. 7/8 breakdowns.  Determinism of the
+The model is event-free: the request-level reference DES invokes
+:meth:`TranslationState.access` in non-decreasing time order (the engine
+runs an operation-for-operation port,
+:class:`repro.core.engine_vec.VecTranslationState`) and the state machine
+returns the translation-resolve time plus the classification used for the
+paper's Fig. 7/8 breakdowns.  Determinism of the
 streaming workloads makes this exact: arrival times never depend on
 translation outcomes (the fabric model is latency-additive; see DESIGN.md §2).
 """

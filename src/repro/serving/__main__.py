@@ -99,10 +99,6 @@ def main(argv=None) -> int:
                    help="enable paper-§6.1 fused pre-translation probes")
     p.add_argument("--prefetch", action="store_true",
                    help="enable paper-§6.2 software TLB prefetch")
-    p.add_argument("--engine", default="event",
-                   choices=("event", "vectorized"),
-                   help="simulation engine (identical results; vectorized "
-                        "is ~10x faster at pod scale)")
     p.add_argument("--profile", default=None, metavar="FILE",
                    help="saved ComputeProfile JSON: calibrated compute "
                         "windows replace the rooflines (loaded jax-free)")
@@ -166,8 +162,8 @@ def main(argv=None) -> int:
         output_mean=args.output_mean, max_decode_slots=args.slots,
         prefill_chunk_tokens=args.prefill_chunk,
         pretranslation=args.pretranslate, prefetch=args.prefetch,
-        trace_path=args.trace, engine=args.engine,
-        profile_path=args.profile, policy=args.policy)
+        trace_path=args.trace, profile_path=args.profile,
+        policy=args.policy)
     if args.disagg is not None:
         dp = DisaggPoint(traffic=pt, prefill_pods=args.disagg[0],
                          decode_pods=args.disagg[1], router=args.router,

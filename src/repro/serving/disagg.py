@@ -32,7 +32,7 @@ first, then prefill pods before decode pods, then lowest pod index);
 per-decode-pod transfers are serialized on that pod's transfer session,
 so decode-side arrival order is nondecreasing by construction and the
 serial and pooled sweep executors (:func:`sweep_disagg`) are bit-for-bit
-identical on both engines.
+identical.
 """
 from __future__ import annotations
 
@@ -259,7 +259,7 @@ def simulate_disagg(arch, requests: List[Request], *,
 
     The KV hop is priced per decode pod on a dedicated ``multi_pod`` pair
     fabric (:func:`~repro.workloads.derive.kv_transfer_fabric`) sharing
-    ``cfg``'s translation/engine/retention knobs — so the L2-reach and
+    ``cfg``'s translation/retention knobs — so the L2-reach and
     retention axes a sweep varies apply to the transfer's Link-MMU too;
     each decode pod's shards ring-allocate through a ``kv_arena_bytes``
     arena (:class:`_TransferLink`), whose footprint against the Link-TLB

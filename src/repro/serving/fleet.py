@@ -29,7 +29,7 @@ step boundaries in global time order (arrival first on ties, lowest
 replica index among replicas), every router/autoscaler input is a pure
 function of that ordering, and each replica's arrival sub-stream is data —
 so the serial and process-pooled sweep executors (:func:`sweep_fleet`)
-return bit-for-bit identical results on both simulation engines.
+return bit-for-bit identical results.
 """
 from __future__ import annotations
 

@@ -21,7 +21,7 @@ walk — the Link-TLB working set was not resident).
 Determinism contract: scheduling decisions are pure functions of the
 admitted request sequence and the committed step timings — no RNG, no wall
 clock — so a batcher replayed on the same inputs reproduces the same plans
-and latency samples bit-for-bit on either simulation engine.
+and latency samples bit-for-bit.
 """
 from __future__ import annotations
 

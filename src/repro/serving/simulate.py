@@ -21,9 +21,10 @@ analytically.  Per-request degradation is then baseline vs ideal
 time-to-first-token on an identical step sequence.
 
 Determinism contract: given the same request list and ``SimConfig``,
-:func:`simulate_traffic` is bit-for-bit deterministic across engines
-(event ≡ vectorized) and sweep executors (:func:`fan_out_points` serial ≡
-process-pooled) — locked by ``tests/test_serving.py``.
+:func:`simulate_traffic` is bit-for-bit deterministic across sweep
+executors (:func:`fan_out_points` serial ≡ process-pooled) — locked by
+``tests/test_serving.py``; the fig15 golden (``tests/test_golden_figs.py``)
+pins its numbers.
 """
 from __future__ import annotations
 
@@ -62,8 +63,7 @@ class ServingStep:
     ideal_comm_ns: float
     compute_ns: float
     walks: int
-    # Vectorized-engine warm-fast-path engagements while pricing this step
-    # (0 on the event engine, which has no fast path to engage).
+    # Warm-fast-path engagements while pricing this step (DESIGN.md §15.2).
     fastpath_calls: int = 0
 
     @property
@@ -142,8 +142,7 @@ class ServingAggregates:
     def cold_steps(self) -> int:
         return sum(1 for s in self.steps if s.walks > 0)
 
-    # Warm-fast-path engagement (vectorized engine only; the event engine
-    # reports 0 everywhere and the fraction is 0.0).
+    # Warm-fast-path engagement (DESIGN.md §15.2).
     @property
     def fastpath_calls(self) -> int:
         return sum(s.fastpath_calls for s in self.steps)
@@ -152,8 +151,8 @@ class ServingAggregates:
     def fastpath_step_fraction(self) -> float:
         """Fraction of priced steps where the warm fast path engaged.
 
-        Steady-state decode traffic on the vectorized engine should sit
-        near 1.0; prefill chunks and post-flush steps are the misses.
+        Steady-state decode traffic should sit near 1.0; prefill chunks
+        and post-flush steps are the misses.
         """
         if not self.steps:
             return float("nan")
@@ -418,7 +417,6 @@ class TrafficPoint:
     pretranslation: bool = False        # paper §6.1 fused probes
     prefetch: bool = False              # paper §6.2 software prefetch
     trace_path: Optional[str] = None    # arrival="trace"
-    engine: str = "event"               # SimConfig.engine (bit-for-bit)
     # Path to a saved ComputeProfile JSON (workloads.calibrate): loaded
     # jax-free *inside* whichever process prices the point, so pooled and
     # serial executors resolve identical calibrated windows.  None keeps
@@ -448,8 +446,7 @@ class TrafficPoint:
     def sim_config(self) -> SimConfig:
         pod = self.pod_spec()
         cfg = SimConfig(fabric=pod_fabric(pod),
-                        tlb_retention_ns=self.retention_ns,
-                        engine=self.engine)
+                        tlb_retention_ns=self.retention_ns)
         if self.l2_entries:
             tr = cfg.translation
             cfg = cfg.replace(translation=dataclasses.replace(

@@ -52,10 +52,6 @@ def main(argv=None) -> int:
     p.add_argument("--retention-ns", type=float, default=None,
                    help="flush TLBs when an idle gap exceeds this (default: "
                         "entries survive gaps)")
-    p.add_argument("--engine", default="event",
-                   choices=("event", "vectorized"),
-                   help="simulation engine (identical results; vectorized "
-                        "is ~10x faster at pod scale)")
     p.add_argument("--calibrate", action="store_true",
                    help="measure the kernel tier and replay with calibrated "
                         "compute windows (cached under calibration/)")
@@ -95,7 +91,7 @@ def main(argv=None) -> int:
                     oversubscription=args.oversub, pod_size=args.pod_size),
         n_gpus=args.gpus, n_steps=args.steps, compute_profile=profile,
         policy=args.policy)
-    cfg = SimConfig(fabric=pod_fabric(trace.pod), engine=args.engine)
+    cfg = SimConfig(fabric=pod_fabric(trace.pod))
     if args.retention_ns is not None:
         cfg = cfg.replace(tlb_retention_ns=args.retention_ns)
 

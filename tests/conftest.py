@@ -1,10 +1,12 @@
 """Shared pytest policy: the `slow` tier.
 
 Tier-1 (`pytest` with the default ``-m "not slow"`` from pyproject.toml)
-must stay well under two minutes; the heavyweight cases below — the largest
-smoke-model (jamba's 8-layer hybrid block) and the long-convergence runtime
-tests — run in CI's separate, non-blocking ``-m slow`` job.  Tests can also
-opt in explicitly with ``@pytest.mark.slow``.
+takes 2-2.5 minutes on 6 xdist workers of an 8-CPU host, and runs the same
+tests every time (no test draws a new random example per run); the
+heavyweight cases below — the largest smoke-model (jamba's 8-layer hybrid
+block) and the long-convergence runtime tests — run in CI's separate,
+non-blocking ``-m slow`` job.  Tests can also opt in explicitly with
+``@pytest.mark.slow``.
 """
 import pytest
 

@@ -1,41 +1,30 @@
 """Tests for the engine micro-benchmark regression gate (benchmarks/run.py).
 
-The CI job measures the fixed grid on both engines, uploads it as an
-artifact, then gates it against the committed
-``benchmarks/BENCH_baseline.json``; these tests pin the gate's semantics —
-most importantly that a synthetic 2x-slower point demonstrably fails and
-that a vectorized engine slower than the event engine fails — without ever
-timing anything.
+The CI job times the fixed grid, uploads it as an artifact, then gates it
+against the committed ``benchmarks/BENCH_baseline.json``; these tests pin
+the gate's semantics — most importantly that a synthetic 2x-slower point
+demonstrably fails — without ever timing anything, and hold the committed
+baseline's recorded results to what the engine computes today.
 """
 import copy
 import json
 import pathlib
+
+import pytest
 
 from benchmarks.run import BASELINE_PATH, _bench_points, check_against
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 
 
-def payload(walls, vec_walls=None):
-    """A BENCH_engine.json-shaped dict over the real grid.
-
-    ``vec_walls`` adds the dual-engine columns (every point, the fleet
-    serving point included); without it the payload has the
-    pre-vectorization single-engine schema, which the gate must still
-    accept (an old baseline after a schema change should not crash it).
-    """
-    points = []
-    for i, ((t, n, b), w) in enumerate(zip(_bench_points(), walls)):
-        p = {"topology": t, "n_gpus": n, "nbytes": b, "wall_s": w}
-        if vec_walls is not None:
-            p["wall_vec_s"] = vec_walls[i]
-            p["speedup"] = round(w / vec_walls[i], 2) if vec_walls[i] else 0.0
-        points.append(p)
-    return {"grid": "engine-v2", "points": points}
+def payload(walls):
+    """A BENCH_engine.json-shaped dict over the real grid."""
+    return {"grid": "engine-v3",
+            "points": [{"topology": t, "n_gpus": n, "nbytes": b, "wall_s": w}
+                       for (t, n, b), w in zip(_bench_points(), walls)]}
 
 
 WALLS = [0.5, 1.0, 0.8, 0.9, 1.2, 0.3, 0.6, 2.0]
-VEC_WALLS = [0.05, 0.2, 0.06, 0.07, 0.05, 0.04, 0.03, 0.4]
 
 
 class TestCheckAgainst:
@@ -83,63 +72,17 @@ class TestCheckAgainst:
         failures = check_against(extra, base, 0.35)
         assert any("not in baseline" in f for f in failures)
 
-
-class TestVectorizedGate:
-    def test_identical_dual_engine_passes(self):
-        base = payload(WALLS, VEC_WALLS)
-        assert check_against(copy.deepcopy(base), base, 0.35) == []
-
-    def test_vectorized_slower_than_event_fails(self):
-        # The whole point of the vectorized engine: on any grid point it
-        # must not lose to the event engine, regardless of the baseline.
-        base = payload(WALLS, VEC_WALLS)
-        cur = copy.deepcopy(base)
-        cur["points"][1]["wall_vec_s"] = 1.5      # event wall is 1.0s
-        failures = check_against(cur, base, 0.35)
-        assert any("slower than event" in f for f in failures)
-
-    def test_vectorized_wall_regression_fails(self):
-        base = payload(WALLS, VEC_WALLS)
-        cur = copy.deepcopy(base)
-        cur["points"][1]["wall_vec_s"] = 0.5      # 2.5x the 0.2s baseline
-        failures = check_against(cur, base, 0.35)
-        assert len(failures) == 1
-        assert "[vec]" in failures[0] and "gpus64" in failures[0]
-
-    def test_vec_vs_event_jitter_floor(self):
-        # Sub-floor inversions on millisecond points are timer noise.
-        base = payload([0.010] + WALLS[1:], [0.008] + VEC_WALLS[1:])
-        cur = copy.deepcopy(base)
-        cur["points"][0]["wall_vec_s"] = 0.012    # > event 0.010, by 2ms
-        assert check_against(cur, base, 0.35) == []
-
-    def test_fleet_point_gates_both_engines(self):
-        # Since the serving hot path the fleet point is dual-engine: both
-        # walls gate against the baseline and the vec-vs-event rule
-        # applies to it like any grid point.
-        base = payload(WALLS, VEC_WALLS)
+    def test_fleet_point_gates(self):
+        # The fleet serving point gates against the baseline like any
+        # grid point.
+        base = payload(WALLS)
         cur = copy.deepcopy(base)
         assert cur["points"][-1]["topology"] == "fleet"
-        assert cur["points"][-1]["wall_vec_s"] == 0.4
         assert check_against(copy.deepcopy(cur), base, 0.35) == []
         cur["points"][-1]["wall_s"] = 4.0         # 2x the 2.0s baseline
         failures = check_against(cur, base, 0.35)
         assert len(failures) == 1
         assert "fleet/gpus16/serving" in failures[0]
-        cur = copy.deepcopy(base)
-        cur["points"][-1]["wall_vec_s"] = 3.0     # slower than event 2.0s
-        failures = check_against(cur, base, 0.35)
-        assert any("slower than event" in f for f in failures)
-
-    def test_old_single_engine_baseline_still_gates(self):
-        # A baseline predating the dual-engine schema gates the event wall
-        # only; the vec-vs-event rule still applies to the current run.
-        base = payload(WALLS)                     # no wall_vec_s
-        cur = payload(WALLS, VEC_WALLS)
-        assert check_against(copy.deepcopy(cur), base, 0.35) == []
-        cur["points"][2]["wall_vec_s"] = 2.0      # event wall is 0.8s
-        failures = check_against(cur, base, 0.35)
-        assert any("slower than event" in f for f in failures)
 
 
 class TestCommittedBaseline:
@@ -153,32 +96,23 @@ class TestCommittedBaseline:
         assert keys == set(_bench_points())
         assert all(p["wall_s"] > 0 for p in base["points"])
 
-    def test_baseline_has_vectorized_walls(self):
-        """Every point — the fleet serving point included — carries the
-        dual-engine schema, and the committed aggregate speedup stays at
-        or above the serving-inclusive headline.  (The aggregate dropped
-        from the pre-serving 20x when the fleet point was folded in: it
-        now averages over scheduler-driven small-collective replay, the
-        regime the paper says matters most, not just pod-scale
-        collectives.)"""
-        with open(ROOT / BASELINE_PATH) as f:
-            base = json.load(f)
-        assert all(p["wall_vec_s"] > 0 for p in base["points"])
-        assert all(p["speedup"] > 0 for p in base["points"])
-        assert base["speedup"] >= 7.0
+    @pytest.mark.parametrize("point", [p for p in _bench_points()
+                                       if p[0] != "fleet"],
+                             ids=lambda p: f"{p[0]}-{p[1]}-{p[2] >> 20}MB")
+    def test_baseline_records_current_results(self, point):
+        """Each committed grid point's simulated results are what the
+        engine computes today: the timed grid prices what it claims to."""
+        from repro.core import ratsim
+        from repro.core.config import FabricConfig, SimConfig
 
-    def test_fleet_serving_speedup_committed(self):
-        """The serving hot path (geometry memoization + warm fast path +
-        batched stepping, DESIGN.md §15) must keep the fleet serving
-        point fast on the vectorized engine.  Target was >= 5x over the
-        pre-optimization committed event wall (2.2026 s); the honest
-        paired best-of measurement floor on the CI-class single-vCPU box
-        is ~4.6x (wall noise is ±20-30%, so both engines are timed
-        interleaved and best-of), which is what the committed baseline
-        records and this gate holds."""
         with open(ROOT / BASELINE_PATH) as f:
-            base = json.load(f)
-        fleet = [p for p in base["points"] if p["topology"] == "fleet"]
-        assert len(fleet) == 1
-        assert fleet[0]["wall_s"] > 0 and fleet[0]["wall_vec_s"] > 0
-        assert fleet[0]["wall_s"] / fleet[0]["wall_vec_s"] >= 4.5
+            base = {(p["topology"], p["n_gpus"], p["nbytes"]): p
+                    for p in json.load(f)["points"]}
+        topo, n, nbytes = point
+        fab = FabricConfig(n_gpus=n, topology=topo, leaf_size=16,
+                           oversubscription=2.0, pod_size=16)
+        c = ratsim.compare(nbytes, n, cfg=SimConfig(fabric=fab))
+        rec = base[point]
+        assert c.baseline.completion_ns == rec["completion_ns"]
+        assert c.degradation == rec["degradation"]
+        assert c.baseline.counters.requests == rec["requests"]

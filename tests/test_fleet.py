@@ -7,7 +7,8 @@ recorded and excluded from percentiles), the queue-depth autoscaler
 retirement) and its central accounting contract — a freshly spun replica
 starts with stone-cold TLBs and re-pays the full cold-walk warmup even
 when the rest of the fleet is warm — plus request conservation, the total
-steps cap, and serial-vs-pooled sweep determinism on both engines.
+steps cap, serial-vs-pooled sweep determinism, and the fleet's priced
+steps against a record.
 """
 import os
 import pathlib
@@ -210,12 +211,12 @@ class TestStepsCap:
 
 # ------------------------------------------------------------------ sweeps
 class TestFleetSweepDeterminism:
-    def _points(self, engine):
+    def _points(self):
         base = TrafficPoint(arch=TINY, rps=300.0, arrival="bursty", seed=9,
                             n_requests=10, burst_size=4, steps_cap=60,
                             prompt_mean=16, output_mean=2,
                             retention_ns=100_000.0, max_decode_slots=4,
-                            prefill_chunk_tokens=32, engine=engine)
+                            prefill_chunk_tokens=32)
         return [
             FleetPoint(traffic=base, replicas=2, router="round_robin"),
             FleetPoint(traffic=base, replicas=2, router="least_loaded",
@@ -223,9 +224,8 @@ class TestFleetSweepDeterminism:
                        scale_down_idle_ns=1e6, spinup_latency_ns=1e5),
         ]
 
-    @pytest.mark.parametrize("engine", ["event", "vectorized"])
-    def test_serial_and_pool_bit_for_bit(self, engine):
-        pts = self._points(engine)
+    def test_serial_and_pool_bit_for_bit(self):
+        pts = self._points()
         serial = sweep_fleet(pts, workers=0)
         pooled = sweep_fleet(pts, workers=2)
         for pt in pts:
@@ -242,19 +242,22 @@ class TestFleetSweepDeterminism:
             assert ([r.rid for r in a.rejected]
                     == [r.rid for r in b.rejected])
 
+    # Fingerprints (test_engine_diff.digest) of each point's priced steps
+    # and TTFT percentiles, recorded from the scalar per-epoch engine the
+    # engine was ported from.  Replicas run interleaved sessions that share
+    # process-cached plans (DESIGN.md §15.1).
+    RECORDED = ["729b5117437de13e", "75ceacdd7151987a"]
+
     def test_engines_agree_bit_for_bit(self):
-        ev = sweep_fleet(self._points("event"), workers=0)
-        vec = sweep_fleet(self._points("vectorized"), workers=0)
-        for a, b in zip(ev.values(), vec.values()):
-            assert ([(s.t_start, s.t_end, s.comm_ns, s.walks)
-                     for s in a.steps]
-                    == [(s.t_start, s.t_end, s.comm_ns, s.walks)
-                        for s in b.steps])
-            assert a.ttft_percentiles() == b.ttft_percentiles()
+        from test_engine_diff import digest
+        res = sweep_fleet(self._points(), workers=0)
+        assert [digest(([(s.t_start, s.t_end, s.comm_ns, s.walks)
+                         for s in r.steps], r.ttft_percentiles()))
+                for r in res.values()] == self.RECORDED
 
     def test_duplicate_points_priced_once(self, monkeypatch):
         import repro.serving.fleet as fleet_mod
-        pts = self._points("event")
+        pts = self._points()
         calls = []
         orig = fleet_mod._fleet_point
 

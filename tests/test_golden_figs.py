@@ -127,9 +127,8 @@ def test_fig13_rows_bit_for_bit():
 
 
 # --------------------------------------------------------------------------
-# fig14/fig15 small-grid goldens (PR 6 values): locked on BOTH engines —
-# the vectorized engine must reproduce the event engine's floats exactly,
-# so one golden table pins the physics of either.
+# fig14/fig15 small-grid goldens (PR 6 values), captured from the scalar
+# per-epoch engine; the engine reproduces its floats exactly.
 # --------------------------------------------------------------------------
 
 # fig14 topology-scaling rows at 1 MB (the full figure sweeps to 1024
@@ -149,19 +148,18 @@ FIG14_GOLDEN = {
 }
 
 
-def _fig14_cfg(topo, n, engine):
+def _fig14_cfg(topo, n):
     from repro.core.config import FabricConfig, SimConfig
     return SimConfig(fabric=FabricConfig(n_gpus=n, topology=topo,
                                          leaf_size=16, oversubscription=2.0,
                                          pod_size=16),
-                     iterations=2, engine=engine)
+                     iterations=2)
 
 
-@pytest.mark.parametrize("engine", ["event", "vectorized"])
 @pytest.mark.parametrize("topo,n", sorted(FIG14_GOLDEN))
-def test_fig14_rows_bit_for_bit(topo, n, engine):
+def test_fig14_rows_bit_for_bit(topo, n):
     cold, warm, i_cold, i_warm, walks = FIG14_GOLDEN[(topo, n)]
-    c = ratsim.compare(1 * MB, n, cfg=_fig14_cfg(topo, n, engine))
+    c = ratsim.compare(1 * MB, n, cfg=_fig14_cfg(topo, n))
     b, i = c.baseline.iterations, c.ideal.iterations
     assert (b[0].completion_ns, b[1].completion_ns) == (cold, warm)
     assert (i[0].completion_ns, i[1].completion_ns) == (i_cold, i_warm)
@@ -189,11 +187,10 @@ FIG15_GOLDEN = dict(
 )
 
 
-@pytest.mark.parametrize("engine", ["event", "vectorized"])
-def test_fig15_bursty_point_bit_for_bit(engine):
+def test_fig15_bursty_point_bit_for_bit():
     from repro.serving.simulate import TrafficPoint, _traffic_point
 
-    r = _traffic_point((TrafficPoint(engine=engine, **FIG15_POINT),))
+    r = _traffic_point((TrafficPoint(**FIG15_POINT),))
     ttft = r.ttft_percentiles()
     assert ttft[50.0] == FIG15_GOLDEN["p50"]
     assert ttft[95.0] == FIG15_GOLDEN["p95"]

@@ -118,8 +118,7 @@ class TestAutoPolicy:
         auto = AutoPolicy()
         sc = auto.scores("allreduce", 1 * MB, FAB8)
         cfg = SimConfig(fabric=FAB8, collective="ring_allreduce",
-                        engine="vectorized", iterations=2, symmetric=True,
-                        collect_trace=False)
+                        iterations=2, symmetric=True, collect_trace=False)
         r = simulate(1 * MB, cfg)
         assert sc["ring_allreduce"] == (r.iterations[0].completion_ns,
                                         r.iterations[1].completion_ns)
@@ -238,7 +237,7 @@ class TestGetPolicy:
 # ---------------------------------------------------------------- sessions
 class TestSessionPolicy:
     def _cfg(self, **kw):
-        return SimConfig(fabric=FAB8, engine="vectorized", **kw)
+        return SimConfig(fabric=FAB8, **kw)
 
     def test_fixed_policy_is_bit_for_bit(self):
         # The same call sequence with and without the policy layer: the
@@ -298,7 +297,7 @@ class TestSessionPolicy:
             == ["rd_allreduce", "ring_allreduce"]
 
     def test_ratsim_session_accepts_policy_spec(self):
-        s = ratsim.session(8, engine="vectorized", policy="fixed")
+        s = ratsim.session(8, policy="fixed")
         assert s.run(1 * MB, collective="allreduce").collective \
             == "ring_allreduce"
 
@@ -318,10 +317,6 @@ class TestSweepValidation:
     def test_sweep_rejects_unknown_topology_eagerly(self):
         with pytest.raises(ValueError, match="unknown topology"):
             ratsim.sweep([1 * MB], [8], topologies=["bogus"], workers=0)
-
-    def test_sweep_rejects_unknown_engine_eagerly(self):
-        with pytest.raises(ValueError, match="unknown engine"):
-            ratsim.sweep([1 * MB], [8], engine="bogus", workers=0)
 
     def test_sweep_logical_collective_needs_policy(self):
         with pytest.raises(ValueError, match="needs a policy"):

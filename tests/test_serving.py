@@ -312,32 +312,27 @@ class TestRetentionContract:
 
 # ------------------------------------------------- warm-fast-path engagement
 class TestWarmFastPath:
-    """DESIGN.md §15.2: steady-state decode runs all-warm on the vec engine.
+    """DESIGN.md §15.2: steady-state decode runs all-warm.
 
     Acceptance criterion for the serving hot path: once prefill is done and
-    the Link-TLBs hold every decode page, the vectorized warm fast path
-    should serve essentially every step — surfaced per step through
+    the Link-TLBs hold every decode page, the warm fast path should serve
+    essentially every step — surfaced per step through
     ``ServingStep.fastpath_calls``.
     """
 
-    def _run(self, engine):
+    def _run(self):
         cfg = SimConfig(fabric=pod_fabric(resolve_pod(
-            PodSpec(n_gpus=16), TINY, "decode")), engine=engine)
+            PodSpec(n_gpus=16), TINY, "decode")))
         # One long-decode request: a single prefill chunk, then ~63 pure
         # decode steps re-touching the same warmed pages.
         reqs = tiny_requests([0.0], prompt=16, output=64)
         return simulate_traffic(TINY, reqs, n_gpus=16, cfg=cfg)
 
     def test_steady_state_decode_engages_fastpath(self):
-        res = self._run("vectorized")
+        res = self._run()
         assert len(res.steps) > 20
         assert res.fastpath_step_fraction > 0.9
         assert res.fastpath_calls > 0
-
-    def test_event_engine_reports_zero(self):
-        res = self._run("event")
-        assert res.fastpath_calls == 0
-        assert res.fastpath_step_fraction == 0.0
 
 
 # ----------------------------------------------------------------- sweeps
@@ -521,9 +516,9 @@ class TinyDisaggMoE(TinyServeMoE):
 TINY_KV = TinyDisaggMoE()
 
 
-def _disagg_cfg(retention=None, engine="event"):
+def _disagg_cfg(retention=None):
     cfg = SimConfig(fabric=pod_fabric(resolve_pod(
-        PodSpec(n_gpus=16), TINY_KV, "decode")), engine=engine)
+        PodSpec(n_gpus=16), TINY_KV, "decode")))
     if retention is not None:
         cfg = cfg.replace(tlb_retention_ns=retention)
     return cfg
@@ -627,12 +622,14 @@ def test_disagg_serial_equals_pooled():
 
 
 def test_disagg_engines_agree():
-    """Event and vectorized engines price disagg bit-for-bit (DESIGN.md §16.4)."""
+    """Disagg prices bit-for-bit what the scalar per-epoch engine the
+    engine was ported from recorded (a ``test_engine_diff.digest`` of the
+    fingerprint): prefill, decode and transfer sessions interleave
+    (DESIGN.md §16.4)."""
+    from test_engine_diff import digest
     reqs = tiny_requests([0.0, 500.0, 1500.0], prompt=24, output=4)
-    runs = [simulate_disagg(TINY_KV, reqs, n_gpus=16,
-                            cfg=_disagg_cfg(engine=eng))
-            for eng in ("event", "vectorized")]
-    assert _disagg_fingerprint(runs[0]) == _disagg_fingerprint(runs[1])
+    res = simulate_disagg(TINY_KV, reqs, n_gpus=16, cfg=_disagg_cfg())
+    assert digest(_disagg_fingerprint(res)) == "94bad909d1994f9b"
 
 
 def test_disagg_off_colocated_bit_for_bit():

@@ -117,40 +117,29 @@ class TestTraceDstSelection:
     trace ``dsts[0]`` unconditionally; it must be the first destination
     that actually *produces flows* (``None`` when no destination does)."""
 
-    @pytest.mark.parametrize("engine", ["event", "vectorized"])
-    def test_asymmetric_group_traces_first_flowing_dst(self, engine):
+    def test_asymmetric_group_traces_first_flowing_dst(self):
         # broadcast is an asymmetric pattern: the root (GPU 0) never
         # receives, so the simulated target set excludes it and the trace
         # must land on the first *receiving* target.
-        cfg = paper_config(8).replace(collect_trace=True, engine=engine)
+        cfg = paper_config(8).replace(collect_trace=True)
         s = SimSession(cfg)
         s.run(1 * MB, collective="broadcast")
         assert s._trace_dst == 1
         r = s.result()
         assert r.trace is not None and (r.trace > 0).any()
 
-    @pytest.mark.parametrize("engine", ["event", "vectorized"])
-    def test_all_zero_byte_collective_traces_none(self, engine):
+    def test_all_zero_byte_collective_traces_none(self):
         # A collective smaller than the group size chunks to zero bytes on
         # every destination — no destination produces flows.  The trace
         # target must fall back to None (the old dsts[0] pick pointed the
         # trace bookkeeping at a flowless engine) and the result trace
         # stays a well-formed all-zeros vector.
-        cfg = paper_config(8).replace(collect_trace=True, engine=engine)
+        cfg = paper_config(8).replace(collect_trace=True)
         s = SimSession(cfg)
         s.run(4)                    # 4 B / 8 GPUs -> zero-byte chunks
         assert s._trace_dst is None
         r = s.result()
         assert r.trace is not None and (r.trace == 0).all()
-
-    def test_trace_identical_across_engines_for_asymmetric_group(self):
-        traces = []
-        for engine in ("event", "vectorized"):
-            cfg = paper_config(8).replace(collect_trace=True, engine=engine)
-            s = SimSession(cfg)
-            s.run(1 * MB, collective="broadcast")
-            traces.append(s.result().trace)
-        assert (traces[0] == traces[1]).all()
 
 
 # ------------------------------------------------------ session-mode oracle

@@ -24,7 +24,7 @@ from repro.core import (ratsim, paper_config, simulate, simulate_ref,
                         SimSession, RefSession, TOPOLOGIES, KB, MB)
 from repro.core.config import (FabricConfig, SimConfig, PreTranslationConfig,
                                PrefetchConfig)
-from repro.core.engine import flows_for_dst
+from repro.core.ref_des import flows_for_dst
 from repro.core.patterns import FlowSpec
 
 
