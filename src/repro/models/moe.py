@@ -5,6 +5,10 @@
                   shardable under plain pjit: experts are sharded on the
                   "experts" logical axis and GSPMD inserts the (all-to-all
                   equivalent) collectives.  Used by train/dry-run steps.
+                  Where no sequence can overflow an expert (capacity >= the
+                  sequence length: every decode step, short prompts) it
+                  builds no capacity buffer: each expert runs over the
+                  tokens themselves and a dense weight combines them.
 
 ``moe_block_ep`` — explicit expert parallelism for ``shard_map`` contexts:
                   tokens are exchanged with ``lax.all_to_all`` over the model
@@ -122,7 +126,16 @@ def moe_gather(p, cfg: ModelConfig, x: jnp.ndarray):
     naturally (batch x expert)-sharded — GSPMD inserts only the expert-axis
     exchange (the all-to-all the paper prices), never a global token
     reshuffle (which it implements as replicate-then-partition and blows
-    per-device memory)."""
+    per-device memory).
+
+    A token picks an expert at most once, so a sequence sends an expert at
+    most S rows.  Where the capacity C is at least S (every decode step,
+    prompts of up to 8 tokens for granite), nothing can be dropped and no
+    capacity buffer is built: token ``s`` takes slot ``s`` of every expert
+    (``[B, E, S, D]``, the input broadcast), and the combine weighs each
+    expert's output with the token's router weight, 0 where the token did
+    not choose it.  Otherwise the rows are scattered into ``[B, E, C, D]``
+    and choices past C are dropped."""
     B, S, D = x.shape
     E, k = cfg.n_experts, cfg.top_k
     C = _capacity(cfg, S)
@@ -138,6 +151,18 @@ def moe_gather(p, cfg: ModelConfig, x: jnp.ndarray):
         ce = jnp.mean(jnp.sum(jax.nn.one_hot(idx, E, dtype=jnp.float32),
                               axis=2), axis=(0, 1))
         aux = E * jnp.sum(me * ce) / k
+
+    if C >= S:
+        with scope(MOE_DISPATCH):
+            buf = with_logical(jnp.broadcast_to(x[:, None], (B, E, S, D)),
+                               ("batch", "experts", None, None))
+            wd = (jax.nn.one_hot(idx, E, dtype=x.dtype)
+                  * w[..., None]).sum(axis=2).swapaxes(1, 2)  # [B, E, S]
+        with scope(MOE_EXPERTS):
+            out_e = _gather_experts(p, cfg, buf, x.dtype)
+        with scope(MOE_COMBINE):
+            y = (out_e * wd[..., None]).sum(axis=1)
+        return y, aux
 
     with scope(MOE_DISPATCH):
         a = idx.reshape(B, S * k)                      # [B, S*k] expert ids

@@ -166,19 +166,104 @@ def _cache_stays_in_place(compiled, caches):
     assert ma.alias_size_in_bytes > 0                # the cache is donated
 
 
-def test_granite_decode_writes_its_cache_in_place_for_v5e(chip):
+def _cell(config, traffic):
+    """A benchmark cell's configuration and traffic files."""
+    with open(os.path.join(BENCH, "configs", config + ".json")) as f:
+        c = json.load(f)
+    with open(os.path.join(BENCH, "traffic", traffic + ".json")) as f:
+        return c, json.load(f)
+
+
+def _expert_dots(text, cfg):
+    """The dots (``dot`` or ``convolution``) of a compiled program that
+    read a layer's expert weights: an operand of E x D x F elements."""
+    shapes = {}
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?(%\S+)\s+=\s+\w+\[([0-9,]*)\]", ln)
+        if m:
+            shapes[m.group(1)] = np.prod([int(d) for d in m.group(2).split(",")
+                                          if d])
+    n = cfg.n_experts * cfg.d_model * cfg.d_ff_expert
+    dots = []
+    for ln in text.splitlines():
+        m = re.search(r" (?:dot|convolution)\(([^)]*)\)", ln)
+        if m and any(shapes.get(a.strip()) == n
+                     for a in m.group(1).split(",")):
+            dots.append(ln.split(" = ", 1)[0].split()[-1])
+    return dots
+
+
+@pytest.fixture(scope="module")
+def granite_decode_cell(chip):
     """The ``granite_decode`` cell's decode step (64 sequences, caches of
-    256 + 512 positions over 24 layers, 2.4 GB)."""
-    with open(os.path.join(BENCH, "traffic", "decode_rounds.json")) as f:
-        t = json.load(f)
+    256 + 512 positions over 24 layers, 2.4 GB), compiled once."""
+    c, t = _cell(GRANITE, "decode_rounds")
     B, P = t["batch"], t["prompt_len"]
-    _, params, prompts, prefill, decode = _granite_steps(
+    cfg, params, prompts, prefill, decode = _granite_steps(
         chip, B, P, P + t["gen_tokens"])
     _, caches = jax.eval_shape(prefill, params, prompts)
     tok = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=chip)
     compiled = decode.lower(params, tok, _on(chip, caches)).compile()
+    return c, t, cfg, caches, compiled
+
+
+def test_granite_decode_writes_its_cache_in_place_for_v5e(granite_decode_cell):
+    *_, caches, compiled = granite_decode_cell
     _fits_one_chip(compiled)
     _cache_stays_in_place(compiled, caches)
+
+
+def test_granite_decode_experts_run_over_the_tokens_for_v5e(
+        granite_decode_cell):
+    """At decode (one token a sequence, capacity 8) ``moe_gather`` builds
+    no capacity buffer: no ``[64, 32, 8, *]`` array is left, a layer
+    compiles to at most 4x the work it needs, and every dot that reads
+    the experts' weights is timed under ``moe.experts``."""
+    from benchmarks.chip.scopes import scope_map
+    from benchmarks.chip.work import decode_step_work
+    from repro.models.scopes import MOE_EXPERTS, SCOPES
+
+    c, t, cfg, _, compiled = granite_decode_cell
+    text = compiled.as_text()
+    B, E = t["batch"], cfg.n_experts
+    for width in (cfg.d_model, cfg.d_ff_expert):
+        assert f"[{B},{E},8,{width}]" not in text
+    # The cost analysis counts the scanned layer's body once: take out the
+    # head (the work of no layers) on both sides and compare one layer at
+    # a round's mean fill.  Each token runs through all E experts, E/k = 4
+    # times the routed work; the experts are ~75% of a layer's flops, so a
+    # layer compiles to ~3.3x.  The capacity buffer (8 slots a token in
+    # every expert) made it ~24x.
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    fill = t["prompt_len"] + t["gen_tokens"] / 2
+
+    def work(layers):
+        return decode_step_work(dict(c, num_hidden_layers=layers), B,
+                                fill)["flops"]
+
+    assert cost["flops"] - work(0) <= 4 * (work(1) - work(0))
+    scopes = scope_map(text, SCOPES)
+    dots = _expert_dots(text, cfg)
+    assert len(dots) == 3                              # gate, up, down
+    assert {scopes[d] for d in dots} == {MOE_EXPERTS}
+
+
+def test_granite_prefill_keeps_its_capacity_buffer_for_v5e(chip):
+    """The ``granite_prefill`` cell's prefill (one 2048-token prompt,
+    capacity 640 < 2048) still scatters into the ``[1, 32, 640, *]``
+    capacity buffer."""
+    from repro.models.moe import _capacity
+
+    _, t = _cell(GRANITE, "prefill_rounds")
+    B, P = t["batch"], t["prompt_len"]
+    cfg, params, prompts, prefill, _ = _granite_steps(
+        chip, B, P, P + t["gen_tokens"])
+    C = _capacity(cfg, P)
+    assert C == 640
+    compiled = prefill.lower(params, prompts).compile()
+    _fits_one_chip(compiled)
+    assert f"[{B},{cfg.n_experts},{C},{cfg.d_model}]" in compiled.as_text()
 
 
 def test_qwen3_ep_step_runs_each_row_through_its_own_expert(topo, chip,
@@ -194,11 +279,8 @@ def test_qwen3_ep_step_runs_each_row_through_its_own_expert(topo, chip,
     from repro.models.base import ParamBuilder
     from repro.models.scopes import EP_EXPERTS, SCOPES
 
-    with open(os.path.join(BENCH, "configs",
-                           "qwen3-moe-235b-a22b-ep4.json")) as f:
-        c = json.load(f)
-    with open(os.path.join(BENCH, "traffic", "ep_decode256.json")) as f:
-        tokens = json.load(f)["tokens_per_chip"]
+    c, t = _cell("qwen3-moe-235b-a22b-ep4", "ep_decode256")
+    tokens = t["tokens_per_chip"]
     monkeypatch.setattr(ops, "on_tpu", lambda: True)   # compile, not interpret
     cfg = configs.get_config(c["arch"]).replace(**c["overrides"])
     n, L = len(topo.devices), cfg.n_layers
@@ -254,11 +336,7 @@ def test_qwen3_share_serving_steps_compile_for_v5e(chip, monkeypatch):
     from repro.models import api
     from repro.models.scopes import MOE_EXPERTS, SCOPES
 
-    with open(os.path.join(BENCH, "configs",
-                           "qwen3-moe-235b-a22b-ep16.json")) as f:
-        c = json.load(f)
-    with open(os.path.join(BENCH, "traffic", "long_prompt_rounds.json")) as f:
-        t = json.load(f)
+    c, t = _cell("qwen3-moe-235b-a22b-ep16", "long_prompt_rounds")
     monkeypatch.setattr(ops, "on_tpu", lambda: True)   # compile, not interpret
     cfg = serving_config(configs.get_config(c["arch"]).replace(
         **c["overrides"]))
